@@ -17,10 +17,11 @@ import json
 import os
 from typing import Dict, List
 
-from repro.launch.mesh import HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import device_peaks
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
-N_ICI_LINKS = 4  # v5e: 4 usable ICI links per chip in a 2-D torus
+#: the chip the dry-run cells are compiled for (a described v5e topology)
+TARGET_KIND = "TPU v5 lite"
 
 
 def analyse(rec: Dict) -> Dict:
@@ -28,9 +29,10 @@ def analyse(rec: Dict) -> Dict:
     flops = rec["hlo_flops_per_device"]
     byts = rec["hlo_bytes_per_device"]
     wire = rec["collectives"]["per_device_wire_bytes"]
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = byts / HBM_BW
-    t_coll = wire / (N_ICI_LINKS * ICI_LINK_BW)
+    peaks = device_peaks(rec.get("device_kind", TARGET_KIND))
+    t_compute = flops / peaks["bf16_flops"]
+    t_memory = byts / peaks["hbm_bw"]
+    t_coll = wire / (peaks["ici_links"] * peaks["ici_link_bw"])
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
@@ -39,7 +41,7 @@ def analyse(rec: Dict) -> Dict:
     useful = model_per_dev / flops if flops else 0.0
     # roofline fraction: useful-compute time / achievable step time
     # (perfect overlap assumption: step time = max of the three terms)
-    frac = (model_per_dev / PEAK_FLOPS_BF16) / bound if bound > 0 else 0.0
+    frac = (model_per_dev / peaks["bf16_flops"]) / bound if bound > 0 else 0.0
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "kind": rec.get("kind"),

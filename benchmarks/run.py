@@ -35,7 +35,7 @@ def _run_manifest() -> dict:
     except Exception:                        # noqa: BLE001 — provenance only
         m["git_sha"] = None
     m["env"] = {k: os.environ.get(k) for k in
-                ("JAX_PLATFORMS", "REPRO_PALLAS_INTERPRET", "PYTHONPATH")}
+                ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR", "PYTHONPATH")}
     try:
         import jax
 
@@ -65,6 +65,9 @@ def main() -> None:
     ap.add_argument("--assert-scaling", action="store_true",
                     help="scaling suite: fail on regression-gate violation")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (compression, engine_batch, gnn_bit,
                             graph_algorithms, kernels_bmm, kernels_bmv,

@@ -48,7 +48,7 @@ def connected_components(g: GraphMatrix, max_iters: Optional[int] = None,
     max_iters = n if max_iters is None else max_iters
     # orientation switching needs the stored transpose; a graph built
     # with with_transpose=False keeps the historical push-only loop
-    if g.ell_t is None and g.backend != "csr" and cfg.mode != "push":
+    if not g.has_transpose and g.backend != "csr" and cfg.mode != "push":
         cfg = DirectionConfig(mode="push")
     gt = g.transposed() if cfg.mode != "push" else g
     avg_degree = g.nnz / max(n, 1)

@@ -111,7 +111,7 @@ def khop_frontier(g: GraphMatrix, source: int, k: int,
     the source seeds ``visited`` and is excluded from the result (so a
     cycle back to the source is not reported, unlike the matrix diagonal).
     """
-    if g.ell_t is None:
+    if not g.has_transpose:
         raise ValueError("khop_frontier needs the transpose "
                          "(with_transpose=True)")
     n = g.n_rows

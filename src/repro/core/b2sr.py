@@ -55,11 +55,15 @@ def static_field(**kw):
 @_pytree
 @dataclasses.dataclass(frozen=True)
 class B2SR:
-    """CSR-over-tiles with bit-packed tiles (compute words are uint32)."""
+    """CSR-over-tiles with bit-packed tiles (compute words are uint32).
 
-    tile_row_ptr: jax.Array  # int32[n_tile_rows + 1]
-    tile_col_idx: jax.Array  # int32[n_tiles]
-    bit_tiles: jax.Array     # uint32[n_tiles, tile_dim]; low tile_dim bits used
+    The host-side storage and interchange format: its arrays are numpy,
+    and the device only ever holds the padded views built from it.
+    """
+
+    tile_row_ptr: np.ndarray  # int32[n_tile_rows + 1]
+    tile_col_idx: np.ndarray  # int32[n_tiles]
+    bit_tiles: np.ndarray     # uint32[n_tiles, tile_dim]; low tile_dim bits used
     tile_dim: int = static_field()
     n_rows: int = static_field()
     n_cols: int = static_field()
@@ -225,26 +229,28 @@ def coo_to_b2sr(
     tile_tr = (uniq_keys // n_tile_cols).astype(np.int64)
     tile_tc = (uniq_keys % n_tile_cols).astype(np.int64)
 
-    tile_row_ptr = np.zeros(n_tile_rows + 1, dtype=np.int32)
-    np.add.at(tile_row_ptr, tile_tr + 1, 1)
-    tile_row_ptr = np.cumsum(tile_row_ptr, dtype=np.int64).astype(np.int32)
+    tile_row_ptr = _row_ptr(tile_tr, n_tile_rows)
 
-    bit_tiles = np.zeros((max(n_tiles, 1), t), dtype=np.uint32)
+    bit_tiles = np.zeros((n_tiles, t), dtype=np.uint32)
     word_idx = (rows % t).astype(np.int64)
     bit = (np.uint32(1) << (cols % t).astype(np.uint32)).astype(np.uint32)
     np.bitwise_or.at(bit_tiles, (inverse, word_idx), bit)
-    if n_tiles == 0:
-        bit_tiles = np.zeros((0, t), dtype=np.uint32)
 
     return B2SR(
-        tile_row_ptr=jnp.asarray(tile_row_ptr),
-        tile_col_idx=jnp.asarray(tile_tc.astype(np.int32)),
-        bit_tiles=jnp.asarray(bit_tiles),
+        tile_row_ptr=tile_row_ptr,
+        tile_col_idx=tile_tc.astype(np.int32),
+        bit_tiles=bit_tiles,
         tile_dim=t,
         n_rows=n_rows,
         n_cols=n_cols,
         nnz=int(rows.shape[0]),
     )
+
+
+def _row_ptr(row_of: np.ndarray, n_rows: int) -> np.ndarray:
+    """CSR row pointer (int32) from each entry's (sorted) row index."""
+    counts = np.bincount(row_of, minlength=n_rows)
+    return np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
 
 
 def csr_to_b2sr(row_ptr: np.ndarray, col_idx: np.ndarray, n_cols: int,
@@ -276,11 +282,36 @@ def b2sr_to_dense(m: B2SR) -> np.ndarray:
     return out[: m.n_rows, : m.n_cols]
 
 
-def to_ell(m: B2SR, max_tiles_per_row: Optional[int] = None,
-           pad_tile_rows_to: int = 1) -> B2SREll:
-    """CSR-over-tiles -> padded ELL view (static shapes for TPU kernels)."""
-    ptr = np.asarray(m.tile_row_ptr)
-    counts = np.diff(ptr)
+def _csr_arrays(m) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Host (tiles-per-row, tile cols, tiles) of a B2SR or its ELL view,
+    tiles in row order — the one input every padded view is built from."""
+    if isinstance(m, B2SREll):
+        col = np.asarray(m.tile_col_idx)
+        valid = col >= 0
+        return (valid.sum(axis=1).astype(np.int64), col[valid],
+                np.asarray(m.bit_tiles)[valid])
+    return (np.diff(np.asarray(m.tile_row_ptr)).astype(np.int64),
+            np.asarray(m.tile_col_idx), np.asarray(m.bit_tiles))
+
+
+def _slab(counts: np.ndarray, tci: np.ndarray, bt: np.ndarray,
+          rows: np.ndarray, k: int, t: int):
+    """Left-justified ``[len(rows), k]`` ELL slab of the given tile-rows."""
+    n = counts[rows]
+    pos = np.arange(int(n.sum())) - np.repeat(np.cumsum(n) - n, n)
+    src = np.repeat((np.cumsum(counts) - counts)[rows], n) + pos
+    local = np.repeat(np.arange(rows.size), n)
+    col = np.full((rows.size, k), -1, dtype=np.int32)
+    tiles = np.zeros((rows.size, k, t), dtype=np.uint32)
+    col[local, pos] = tci[src]
+    tiles[local, pos] = bt[src]
+    return col, tiles
+
+
+def ell_arrays(m, max_tiles_per_row: Optional[int] = None,
+               pad_tile_rows_to: int = 1):
+    """Host ``(col, tiles, counts)`` of the padded ELL view of ``m``."""
+    counts, tci, bt = _csr_arrays(m)
     k = int(counts.max()) if counts.size else 1
     if max_tiles_per_row is not None:
         if max_tiles_per_row < k:
@@ -288,54 +319,59 @@ def to_ell(m: B2SR, max_tiles_per_row: Optional[int] = None,
         k = max_tiles_per_row
     k = max(k, 1)
     n_tr = m.n_tile_rows
-    n_tr_pad = ceil_div(n_tr, pad_tile_rows_to) * pad_tile_rows_to
-    t = m.tile_dim
-
-    col = np.full((n_tr_pad, k), -1, dtype=np.int32)
-    tiles = np.zeros((n_tr_pad, k, t), dtype=np.uint32)
-    tci = np.asarray(m.tile_col_idx)
-    bt = np.asarray(m.bit_tiles)
-    for i in range(n_tr):
-        s, e = int(ptr[i]), int(ptr[i + 1])
-        col[i, : e - s] = tci[s:e]
-        tiles[i, : e - s] = bt[s:e]
-    return B2SREll(
-        tile_col_idx=jnp.asarray(col),
-        bit_tiles=jnp.asarray(tiles),
-        row_n_tiles=jnp.asarray(
-            np.pad(counts.astype(np.int32), (0, n_tr_pad - n_tr))),
-        tile_dim=t,
-        n_rows=m.n_rows,
-        n_cols=m.n_cols,
-    )
+    counts = np.pad(counts, (0, ceil_div(n_tr, pad_tile_rows_to)
+                             * pad_tile_rows_to - n_tr))
+    col, tiles = _slab(counts, tci, bt, np.arange(counts.size), k,
+                       m.tile_dim)
+    return col, tiles, counts.astype(np.int32)
 
 
-def to_bucketed(ell: B2SREll, max_buckets: int = 8) -> B2SRBucketedEll:
-    """ELL view -> row-bucketed (SELL-style) view.
+def to_ell(m: B2SR, max_tiles_per_row: Optional[int] = None,
+           pad_tile_rows_to: int = 1) -> B2SREll:
+    """CSR-over-tiles -> padded ELL view (static shapes for TPU kernels).
+
+    Every tile-row is padded to the widest one, so on a skewed graph this
+    view can be many times the tile bytes; the default compute path uses
+    :func:`to_bucketed` instead and builds this only on request.
+    """
+    col, tiles, counts = ell_arrays(m, max_tiles_per_row, pad_tile_rows_to)
+    # ensure_compile_time_eval: like the bucketed view, this one is built
+    # lazily and memoized — possibly first inside a jit trace
+    with jax.ensure_compile_time_eval():
+        return B2SREll(
+            tile_col_idx=jnp.asarray(col),
+            bit_tiles=jnp.asarray(tiles),
+            row_n_tiles=jnp.asarray(counts),
+            tile_dim=m.tile_dim,
+            n_rows=m.n_rows,
+            n_cols=m.n_cols,
+        )
+
+
+def to_bucketed(m, max_buckets: int = 8) -> B2SRBucketedEll:
+    """Tile CSR (or an ELL view of it) -> row-bucketed (SELL-style) view.
 
     Tile-rows are grouped by tile count into power-of-two ranges
     ``(2^(b-1), 2^b]``; each group's slab width is its own max count (so
     per-row padding is < 2x even inside a bucket). If the count histogram
-    spans more than ``max_buckets`` ranges, the widest ranges are merged
-    into one slab of width ``max(counts)`` — hubs are few, so the merged
-    bucket's padding is paid by few rows. Empty tile-rows are dropped.
+    spans more than ``max_buckets`` ranges, the *narrowest* ranges are
+    merged into one slab: their rows are short, so widening them costs
+    little, while a merged hub slab would pad thousands of rows to the
+    widest row. Empty tile-rows are dropped. The slabs are built straight
+    from the tile CSR, never through the single-max ELL.
     """
-    counts = np.asarray(ell.row_n_tiles, dtype=np.int64)
-    n_tr = int(ell.tile_col_idx.shape[0])
-    col_np = np.asarray(ell.tile_col_idx)
-    tiles_np = np.asarray(ell.bit_tiles)
-
+    counts, tci, bt = _csr_arrays(m)
+    n_tr = m.n_tile_rows
     nonempty = np.flatnonzero(counts > 0)
     cols_out, tiles_out, rows_out = [], [], []
     if nonempty.size:
         # power-of-two bucket index per row: 1 -> 0, 2 -> 1, 3..4 -> 2, ...
         bidx = np.ceil(np.log2(counts[nonempty])).astype(np.int64)
-        uniq = np.sort(np.unique(bidx))
+        uniq = np.unique(bidx)
         if uniq.size > max_buckets:
-            # merge the widest ranges into one hub bucket
-            keep = uniq[: max_buckets - 1]
-            bidx = np.where(np.isin(bidx, keep), bidx, uniq[max_buckets - 1])
-            uniq = np.sort(np.unique(bidx))
+            cap = uniq[uniq.size - max_buckets]
+            bidx = np.maximum(bidx, cap)
+            uniq = np.unique(bidx)
         # ensure_compile_time_eval: the bucketed view is built lazily and
         # memoized on the GraphMatrix — when the first use happens inside a
         # jit trace, plain jnp.asarray would mint tracers and poison the
@@ -344,16 +380,17 @@ def to_bucketed(ell: B2SREll, max_buckets: int = 8) -> B2SRBucketedEll:
             for b in uniq:
                 rows_b = nonempty[bidx == b]
                 k_b = int(counts[rows_b].max())
-                cols_out.append(jnp.asarray(col_np[rows_b, :k_b]))
-                tiles_out.append(jnp.asarray(tiles_np[rows_b, :k_b]))
+                col, tiles = _slab(counts, tci, bt, rows_b, k_b, m.tile_dim)
+                cols_out.append(jnp.asarray(col))
+                tiles_out.append(jnp.asarray(tiles))
                 rows_out.append(jnp.asarray(rows_b.astype(np.int32)))
     return B2SRBucketedEll(
         col_idx=tuple(cols_out),
         bit_tiles=tuple(tiles_out),
         rows=tuple(rows_out),
-        tile_dim=ell.tile_dim,
-        n_rows=ell.n_rows,
-        n_cols=ell.n_cols,
+        tile_dim=m.tile_dim,
+        n_rows=m.n_rows,
+        n_cols=m.n_cols,
         n_tile_rows=n_tr,
     )
 
@@ -361,27 +398,20 @@ def to_bucketed(ell: B2SREll, max_buckets: int = 8) -> B2SRBucketedEll:
 def transpose(m: B2SR) -> B2SR:
     """B2SR transpose: swap tile coords (CSR->CSC relabel) + bit-transpose tiles.
 
-    The paper uses cusparseScsr2csc for the top level; tiles are transposed by
-    re-packing. We transpose tiles with the word-level bit transpose below.
+    The paper uses cusparseScsr2csc for the top level; tiles are transposed
+    word-level (:func:`bit_transpose_np`), never unpacked to bits.
     """
     t = m.tile_dim
     ptr = np.asarray(m.tile_row_ptr)
     tile_tr = np.repeat(np.arange(m.n_tile_rows, dtype=np.int64), np.diff(ptr))
     tile_tc = np.asarray(m.tile_col_idx, dtype=np.int64)
-    tiles = np.asarray(m.bit_tiles)
 
-    order = np.argsort(tile_tc * m.n_tile_rows + tile_tr, kind="stable")
-    new_tr = tile_tc[order]
-    new_tc = tile_tr[order].astype(np.int32)
-    new_tiles = bit_transpose_np(tiles[order], t)
-
-    new_ptr = np.zeros(m.n_tile_cols + 1, dtype=np.int64)
-    np.add.at(new_ptr, new_tr + 1, 1)
-    new_ptr = np.cumsum(new_ptr).astype(np.int32)
+    # stable by tile column: within a column the tile rows stay ascending
+    order = np.argsort(tile_tc, kind="stable")
     return B2SR(
-        tile_row_ptr=jnp.asarray(new_ptr),
-        tile_col_idx=jnp.asarray(new_tc),
-        bit_tiles=jnp.asarray(new_tiles),
+        tile_row_ptr=_row_ptr(tile_tc[order], m.n_tile_cols),
+        tile_col_idx=tile_tr[order].astype(np.int32),
+        bit_tiles=bit_transpose_np(np.asarray(m.bit_tiles)[order], t),
         tile_dim=t,
         n_rows=m.n_cols,
         n_cols=m.n_rows,
@@ -390,11 +420,26 @@ def transpose(m: B2SR) -> B2SR:
 
 
 def bit_transpose_np(tiles: np.ndarray, t: int) -> np.ndarray:
-    """Transpose each t-row bit tile (numpy, conversion-time)."""
-    bits = (tiles[..., :, None] >> np.arange(t, dtype=np.uint32)) & 1  # [..., t(row), t(col)]
-    bits_t = np.swapaxes(bits, -1, -2)
-    return (bits_t.astype(np.uint32) << np.arange(t, dtype=np.uint32)).sum(
-        axis=-1, dtype=np.uint32)
+    """Transpose each t-row bit tile with word-level block swaps (numpy).
+
+    ``log2(t)`` rounds; round ``j`` swaps the off-diagonal ``j × j`` blocks
+    of every ``2j × 2j`` block with one masked shift-XOR per word pair
+    (Hacker's Delight ``transpose32``, LSB-first), in place on strided
+    views. Works on uint32 words only: it never materialises the bits.
+    """
+    out = np.array(tiles, dtype=np.uint32, copy=True)
+    words = out.reshape(-1, t)
+    j = t // 2
+    while j:
+        mask = np.uint32(sum(1 << p for p in range(t) if not p & j))
+        pairs = words.reshape(-1, t // (2 * j), 2, j)
+        lo, hi = pairs[:, :, 0, :], pairs[:, :, 1, :]
+        x = ((lo >> np.uint32(j)) ^ hi) & mask
+        hi ^= x
+        x <<= np.uint32(j)
+        lo ^= x
+        j //= 2
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -557,9 +602,6 @@ def packed_grid_to_b2sr(grid: np.ndarray, n_rows: int, n_cols: int) -> B2SR:
                          f"({n_rows}, {n_cols}) at tile_dim {t}")
     tr, tc = np.nonzero(grid.any(axis=-1))
     tiles = grid[tr, tc].astype(np.uint32)
-    ptr = np.zeros(R + 1, dtype=np.int64)
-    np.add.at(ptr, tr + 1, 1)
-    ptr = np.cumsum(ptr).astype(np.int32)
     if not tiles.size:
         nnz = 0
     elif hasattr(np, "bitwise_count"):        # numpy >= 2.0
@@ -567,9 +609,9 @@ def packed_grid_to_b2sr(grid: np.ndarray, n_rows: int, n_cols: int) -> B2SR:
     else:
         nnz = int(np.unpackbits(tiles.view(np.uint8)).sum())
     return B2SR(
-        tile_row_ptr=jnp.asarray(ptr),
-        tile_col_idx=jnp.asarray(tc.astype(np.int32)),
-        bit_tiles=jnp.asarray(tiles),
+        tile_row_ptr=_row_ptr(tr, R),
+        tile_col_idx=tc.astype(np.int32),
+        bit_tiles=tiles,
         tile_dim=t,
         n_rows=n_rows,
         n_cols=n_cols,

@@ -1,9 +1,9 @@
 """High-level GraphBLAS matrix object: one generic operation API.
 
 ``GraphMatrix`` is what algorithms and models consume. It bundles:
-  - the B2SR representation (+ optional transposed B2SR for vxm),
+  - the B2SR tile CSR on the host (+ optional transposed B2SR for vxm),
   - the float CSR baseline representation (the GraphBLAST stand-in),
-  - padded ELL views for the static-shape TPU kernel path.
+  - padded views for the static-shape TPU kernel path, built on first use.
 
 The operation surface is two generic ops (DESIGN.md §10):
 
@@ -26,9 +26,9 @@ input-transpose, replace semantics, and row chunking travel in one
 
 Load balancing: both b2sr backends transparently run the row-bucketed
 (SELL-style) path when ``use_buckets`` is on (the default) — ``ell_buckets``
-is built lazily from the ELL view on first use (DESIGN.md §2).
+is built lazily from the tile CSR on first use (DESIGN.md §2).
 ``row_chunk`` callers keep the single-ELL path (chunking needs one uniform
-row axis).
+row axis); that padded view is built only when such a path first asks.
 
 The pre-registry per-row method names (``mxv_bool``, ``mxv_count``,
 ``spmm``, ``spmm_bool``, ``mxm_count``) survive as deprecation shims:
@@ -121,8 +121,10 @@ class GraphMatrix:
     n_cols: int
     nnz: int
     tile_dim: int
-    ell: B2SREll
-    ell_t: Optional[B2SREll]          # transpose, for vxm / pull traversal
+    # the host tile CSR every padded view is built from, and its transpose
+    # (for vxm / pull traversal); None for a graph wrapped around an ELL
+    tiles: Optional[B2SR]
+    tiles_t: Optional[B2SR]
     csr: csr_mod.CSRMatrix
     csr_t: Optional[csr_mod.CSRMatrix]
     backend: str = "b2sr"
@@ -131,6 +133,11 @@ class GraphMatrix:
     ell_buckets: Optional[B2SRBucketedEll] = None
     ell_buckets_t: Optional[B2SRBucketedEll] = None
     use_buckets: bool = True
+    # single-max padded ELL views, built only when a path asks for them
+    # (use_buckets off, row_chunk, sharding, k-hop): on a skewed graph
+    # they can be many times the tile bytes
+    ell_cache: Optional[B2SREll] = None
+    ell_t_cache: Optional[B2SREll] = None
     # lazy caches (same pattern as ell_buckets): the out-degree vector, the
     # transposed view, the structure fingerprint used by engine/planner,
     # and tri_count's strict-lower-triangle operand pair
@@ -159,24 +166,10 @@ class GraphMatrix:
     @staticmethod
     def from_coo(rows: np.ndarray, cols: np.ndarray, n_rows: int, n_cols: int,
                  tile_dim: int = 32, with_transpose: bool = True,
-                 backend: str = "b2sr",
-                 max_tiles_per_row: Optional[int] = None) -> "GraphMatrix":
-        if backend not in BACKENDS:
-            raise ValueError(f"backend must be one of {BACKENDS}")
+                 backend: str = "b2sr") -> "GraphMatrix":
         mat = b2sr_mod.coo_to_b2sr(rows, cols, n_rows, n_cols, tile_dim)
-        ell = b2sr_mod.to_ell(mat, max_tiles_per_row)
-        ell_t = None
-        csr_t = None
-        if with_transpose:
-            mt = b2sr_mod.transpose(mat)
-            ell_t = b2sr_mod.to_ell(mt, max_tiles_per_row)
-            csr_t = csr_mod.from_coo(cols, rows, n_cols, n_rows)
-        return GraphMatrix(
-            n_rows=n_rows, n_cols=n_cols, nnz=mat.nnz, tile_dim=tile_dim,
-            ell=ell, ell_t=ell_t,
-            csr=csr_mod.from_coo(rows, cols, n_rows, n_cols), csr_t=csr_t,
-            backend=backend,
-        )
+        return GraphMatrix._from_tiles(mat, rows, cols, with_transpose,
+                                       backend)
 
     @staticmethod
     def from_dense(mat: np.ndarray, tile_dim: int = 32, **kw) -> "GraphMatrix":
@@ -186,29 +179,51 @@ class GraphMatrix:
 
     @staticmethod
     def from_b2sr(mat: B2SR, with_transpose: bool = True,
-                  backend: str = "b2sr",
-                  max_tiles_per_row: Optional[int] = None) -> "GraphMatrix":
+                  backend: str = "b2sr") -> "GraphMatrix":
         """Wrap an already-built B2SR (e.g. an mxm output) without re-packing.
 
         The CSR twin is derived from the same tiles (one unpack), not by a
         second COO -> B2SR conversion.
         """
+        rows, cols = b2sr_mod.b2sr_to_coo(mat)
+        return GraphMatrix._from_tiles(mat, rows, cols, with_transpose,
+                                       backend)
+
+    @staticmethod
+    def _from_tiles(mat: B2SR, rows, cols, with_transpose: bool,
+                    backend: str) -> "GraphMatrix":
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}")
-        rows, cols = b2sr_mod.b2sr_to_coo(mat)
-        ell = b2sr_mod.to_ell(mat, max_tiles_per_row)
-        ell_t = None
-        csr_t = None
-        if with_transpose:
-            mt = b2sr_mod.transpose(mat)
-            ell_t = b2sr_mod.to_ell(mt, max_tiles_per_row)
-            csr_t = csr_mod.from_coo(cols, rows, mat.n_cols, mat.n_rows)
         return GraphMatrix(
             n_rows=mat.n_rows, n_cols=mat.n_cols, nnz=mat.nnz,
-            tile_dim=mat.tile_dim, ell=ell, ell_t=ell_t,
+            tile_dim=mat.tile_dim, tiles=mat,
+            tiles_t=b2sr_mod.transpose(mat) if with_transpose else None,
             csr=csr_mod.from_coo(rows, cols, mat.n_rows, mat.n_cols),
-            csr_t=csr_t, backend=backend,
+            csr_t=(csr_mod.from_coo(cols, rows, mat.n_cols, mat.n_rows)
+                   if with_transpose else None),
+            backend=backend,
         )
+
+    # -- padded views (built on first use, then memoized) --------------------
+    @property
+    def ell(self) -> B2SREll:
+        if self.ell_cache is None:
+            self.ell_cache = b2sr_mod.to_ell(self.tiles)
+        return self.ell_cache
+
+    @property
+    def ell_t(self) -> Optional[B2SREll]:
+        if self.ell_t_cache is None and self.tiles_t is not None:
+            self.ell_t_cache = b2sr_mod.to_ell(self.tiles_t)
+        return self.ell_t_cache
+
+    @property
+    def has_transpose(self) -> bool:
+        return self.tiles_t is not None or self.ell_t_cache is not None
+
+    def _tiles_or_ell(self):
+        """The host tile CSR, or the ELL of a graph wrapped around one."""
+        return self.tiles if self.tiles is not None else self.ell
 
     def with_backend(self, backend: str) -> "GraphMatrix":
         if backend not in BACKENDS:
@@ -237,16 +252,19 @@ class GraphMatrix:
         """
         if self.transposed_cache is not None:
             return self.transposed_cache
-        if self.ell_t is None:
+        if not self.has_transpose:
             raise ValueError("GraphMatrix built without transpose "
                              "(with_transpose=True)")
         # build (and cache on *self*) the transpose's bucketed view before
         # swapping, so the cached view shares it with this instance
         if (self.use_buckets and self.backend != "csr"
                 and self.ell_buckets_t is None):
-            self.ell_buckets_t = b2sr_mod.to_bucketed(self.ell_t)
+            self.ell_buckets_t = b2sr_mod.to_bucketed(
+                self.tiles_t if self.tiles_t is not None else self.ell_t)
         gt = dataclasses.replace(
-            self, ell=self.ell_t, ell_t=self.ell, csr=self.csr_t,
+            self, tiles=self.tiles_t, tiles_t=self.tiles,
+            ell_cache=self.ell_t_cache, ell_t_cache=self.ell_cache,
+            csr=self.csr_t,
             csr_t=self.csr, ell_buckets=self.ell_buckets_t,
             ell_buckets_t=self.ell_buckets, n_rows=self.n_cols,
             n_cols=self.n_rows, degrees_cache=None, transposed_cache=self,
@@ -257,9 +275,9 @@ class GraphMatrix:
         return gt
 
     def buckets(self) -> B2SRBucketedEll:
-        """The bucketed view of ``ell``, built lazily and cached."""
+        """The bucketed view, built lazily from the tile CSR and cached."""
         if self.ell_buckets is None:
-            self.ell_buckets = b2sr_mod.to_bucketed(self.ell)
+            self.ell_buckets = b2sr_mod.to_bucketed(self._tiles_or_ell())
         return self.ell_buckets
 
     def _bucketed(self, row_chunk: Optional[int] = None) -> bool:
@@ -314,20 +332,26 @@ class GraphMatrix:
         # fall back to the ELL slab if a later with_buckets(True) finds a
         # partition without them (correct, just not SELL-balanced — reshard
         # to get harmonised buckets back)
-        part = partition_mod.partition_rows(self.ell, n_shards,
+        part = partition_mod.partition_rows(self._tiles_or_ell(),
+                                            n_shards,
                                             with_buckets=self.use_buckets,
                                             max_buckets=max_buckets,
                                             balanced=balanced)
         part_t = None
-        if self.ell_t is not None:
+        if self.has_transpose:
             part_t = partition_mod.partition_rows(
-                self.ell_t, n_shards, with_buckets=self.use_buckets,
-                max_buckets=max_buckets, balanced=balanced)
+                self.tiles_t if self.tiles_t is not None else self.ell_t,
+                n_shards,
+                with_buckets=self.use_buckets, max_buckets=max_buckets,
+                balanced=balanced)
         xplan = xplan_t = None
         if combine == "exchange":
             xplan = partition_mod.build_exchange_plan(part)
             if part_t is not None:
                 xplan_t = partition_mod.build_exchange_plan(part_t)
+        part = partition_mod.place(part, mesh, axes)
+        if part_t is not None:
+            part_t = partition_mod.place(part_t, mesh, axes)
         self._publish_partition_quality(part, part_t, n_shards)
         return dataclasses.replace(
             self, mesh=mesh, shard_axes=axes, partitioned=part,
@@ -728,14 +752,20 @@ class GraphMatrix:
     def fingerprint(self) -> str:
         """Content hash of the graph structure (the plan-cache key component).
 
-        Hashes the ELL tile layout + bit tiles once per instance (memoized;
-        backend/bucket toggles keep it — they are separate plan-key fields).
+        Hashes the tile CSR (row pointer, tile columns, bit tiles) once per
+        instance (memoized; backend/bucket toggles keep it — they are
+        separate plan-key fields). A graph wrapped around an ELL view hashes
+        that view instead.
         """
         if self.fingerprint_cache is None:
             h = hashlib.blake2b(digest_size=16)
             h.update(f"{self.n_rows}:{self.n_cols}:{self.nnz}:"
                      f"{self.tile_dim}".encode())
-            h.update(np.asarray(self.ell.tile_col_idx).tobytes())
-            h.update(np.asarray(self.ell.bit_tiles).tobytes())
+            src = self.tiles if self.tiles is not None else self.ell
+            arrays = ((src.tile_row_ptr, src.tile_col_idx, src.bit_tiles)
+                      if self.tiles is not None
+                      else (src.tile_col_idx, src.bit_tiles))
+            for a in arrays:
+                h.update(np.ascontiguousarray(a).tobytes())
             self.fingerprint_cache = h.hexdigest()
         return self.fingerprint_cache

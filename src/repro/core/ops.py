@@ -42,14 +42,6 @@ from repro.core.semiring import Semiring, ARITHMETIC, BOOLEAN, MIN_PLUS
 def _popcount(x: jax.Array) -> jax.Array:
     return jax.lax.population_count(x)
 
-def shard_map_compat(*args, **kwargs):
-    """jax.shard_map where it exists (jax >= 0.5), experimental fallback."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(*args, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(*args, **kwargs)
-
-
 def _reduce(semiring: Semiring, arr: jax.Array, axis) -> jax.Array:
     """⊕-reduction along ``axis`` for the supported monoids."""
     if semiring.add is jnp.add:
@@ -168,9 +160,9 @@ def bmv_bin_bin_bin_pull(ell: B2SREll, x_packed: jax.Array,
     Pull traversal is the *same* bin·bin→bin reduction over the transposed
     operand the caller already passes (``direction`` never re-transposes);
     what differs is the evaluation order — the Pallas twin
-    (``kernels.bmv.bmv_bin_bin_bin_pull_pallas``) walks each output row's
-    k-axis through an early-exit loop and stops on the first set bit of
-    every §V-allowed lane. jnp has no data-dependent row exit (SIMD over
+    (``kernels.bmv.ops.bmv_bin_bin_bin_pull``) stops computing a row block
+    once every §V-allowed lane of it is set. jnp has no data-dependent row
+    exit (SIMD over
     the whole slab), so this twin runs the identical ``_bmv_bbb_block``
     math as masked push — which is exactly what makes the pull row
     bit-exact against push by construction (DESIGN.md §12).
@@ -358,10 +350,9 @@ def _spmm_block(col_idx: jax.Array, tiles: jax.Array, x3: jax.Array, t: int,
                                 preferred_element_type=out_dtype), None
 
     acc0 = jnp.zeros((col_idx.shape[0], t, d), dtype=out_dtype)
-    if vma_axes and hasattr(jax.lax, "pvary"):
+    if vma_axes:
         # under shard_map the body output varies over the mesh axes;
-        # the init carry must be marked varying too (scan-vma rule,
-        # jax >= 0.5; older jax has no vma tracking to satisfy)
+        # the init carry must be marked varying too (scan-vma rule)
         acc0 = jax.lax.pvary(acc0, tuple(vma_axes))
     acc, _ = jax.lax.scan(step, acc0, jnp.arange(K))
     return acc

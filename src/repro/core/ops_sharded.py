@@ -71,8 +71,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-import functools
-import inspect
 
 from repro.core import ops as core_ops
 from repro.core.b2sr import (B2SREll, ceil_div, ell_to_packed_grid,
@@ -82,29 +80,8 @@ from repro.core.operands import pad_leading
 from repro.core.ops import (_bff_setup, _bmv_bbb_block, _bmv_bbf_block,
                             _bmv_bff_block, _mxm_bbb_block, _mxm_bbf_block,
                             _spmm_bbb_block, _spmm_bbf_block, _spmm_block,
-                            apply_frontier_mask, apply_grid_mask,
-                            shard_map_compat)
+                            apply_frontier_mask, apply_grid_mask)
 from repro.core.partition import (ExchangePlan, PartitionedB2SR, shard_count)
-
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_kwargs() -> dict:
-    """Disable the replication/varying check where the kwarg exists.
-
-    The bodies here are collective-closed (gather/psum/exchange before
-    return), but the older checker rejects scan carries inside them; probe
-    the actual shard_map signature once instead of try/except-ing every
-    call (which would re-trace the body and misattribute unrelated
-    TypeErrors).
-    """
-    fn = jax.shard_map if hasattr(jax, "shard_map") else None
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    for kw in ("check_rep", "check_vma"):
-        if kw in params:
-            return {kw: False}
-    return {}
 
 
 class _LocalShard:
@@ -255,9 +232,8 @@ def _sharded_call(g, local_fn, rhs_arrays: Tuple, combine: str = "gather",
                 return jax.lax.psum(y, axes)
             return jax.lax.all_gather(y, axes, axis=0, tiled=True)
 
-        y = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(),
-                             **_shard_map_kwargs())(*slabs, *rhs_arrays)
+        y = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=P(), check_vma=False)(*slabs, *rhs_arrays)
         rhs_words = sum(int(jnp.size(a)) for a in rhs_arrays)
         P_n = part.n_shards
         if combine == "gather":
@@ -336,9 +312,8 @@ def _sharded_call(g, local_fn, rhs_arrays: Tuple, combine: str = "gather",
         return out[:-1]
 
     out_specs = P(axes, *([None] * (out_ndim - 1)))
-    y = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs,
-                         **_shard_map_kwargs())(*slabs, *idx, rhs)
+    y = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)(*slabs, *idx, rhs)
     rhs_lane = 1
     for s in rhs.shape[1:]:
         rhs_lane *= int(s)
@@ -369,15 +344,13 @@ def _mxv_bin_words(g, xw, bucketed: bool) -> jax.Array:
     # an empty graph) runs the ELL slab — identical results, no SELL split
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.bmv import ops as bmv_ops
         if bucketed:
             def local(view, x):
                 out = jnp.zeros((view.rows + 1,), jnp.uint32)
                 return view.scatter_buckets(
                     out, lambda cb, tb: bmv_ops.bmv_bin_bin_bin(
-                        _bucket_ell(cb, tb, t, part.n_cols), x,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 8)))
+                        _bucket_ell(cb, tb, t, part.n_cols), x))
         else:
             def local(view, x):
                 return bmv_ops.bmv_bin_bin_bin(view.ell(part.n_cols), x)
@@ -468,16 +441,13 @@ def _mxv_count_vals(g, xw, call, bucketed: bool) -> jax.Array:
 
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.bmv import ops as bmv_ops
         if bucketed:
             def local(view, x):
                 out = jnp.zeros((view.rows + 1, t), dt)
                 return view.scatter_buckets(
                     out, lambda cb, tb: bmv_ops.bmv_bin_bin_full(
-                        _bucket_ell(cb, tb, t, part.n_cols), x, dt,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 8)
-                    ).reshape(-1, t))
+                        _bucket_ell(cb, tb, t, part.n_cols), x, dt).reshape(-1, t))
         else:
             def local(view, x):
                 return bmv_ops.bmv_bin_bin_full(
@@ -545,7 +515,6 @@ def _mxv_dense_vals(g, x, call, bucketed: bool) -> jax.Array:
 
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.bmv import ops as bmv_ops
         # the wrapper pads/stages the flat vector itself, so the local body
         # recovers it from the (possibly exchange-widened) tile-word layout
@@ -555,9 +524,7 @@ def _mxv_dense_vals(g, x, call, bucketed: bool) -> jax.Array:
                 out = jnp.full((view.rows + 1, t), ident, dtype=xr.dtype)
                 return view.scatter_buckets(
                     out, lambda cb, tb: bmv_ops.bmv_bin_full_full(
-                        _bucket_ell(cb, tb, t, part.n_cols), xf, sr, av,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 8)
-                    ).reshape(-1, t))
+                        _bucket_ell(cb, tb, t, part.n_cols), xf, sr, av).reshape(-1, t))
         else:
             def local(view, xr):
                 xf = xr.reshape(-1)[: part.n_cols]
@@ -633,7 +600,6 @@ def _mxm_dense_vals(g, x, call, bucketed: bool) -> jax.Array:
 
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.spmm import ops as spmm_ops
         if bucketed:
             def local(view, xr):
@@ -641,9 +607,7 @@ def _mxm_dense_vals(g, x, call, bucketed: bool) -> jax.Array:
                 out = jnp.zeros((view.rows + 1, t, d), dtype=x.dtype)
                 return view.scatter_buckets(
                     out, lambda cb, tb: spmm_ops.spmm(
-                        _bucket_ell(cb, tb, t, part.n_cols), x2,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 4)
-                    ).reshape(-1, t, d))
+                        _bucket_ell(cb, tb, t, part.n_cols), x2).reshape(-1, t, d))
         else:
             def local(view, xr):
                 x2 = xr.reshape(-1, d)[: part.n_cols]
@@ -711,16 +675,13 @@ def _mxm_bitmat_vals(g, xw, call, bucketed: bool) -> jax.Array:
 
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.spmm import ops as spmm_ops
         if bucketed:
             def local(view, xr):
                 out = jnp.zeros((view.rows + 1, t, d), dtype=dt)
                 return view.scatter_buckets(
                     out, lambda cb, tb: spmm_ops.spmm_bin_bin_full(
-                        _bucket_ell(cb, tb, t, part.n_cols), xr, dt,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 4)
-                    ).reshape(-1, t, d))
+                        _bucket_ell(cb, tb, t, part.n_cols), xr, dt).reshape(-1, t, d))
         else:
             def local(view, xr):
                 return spmm_ops.spmm_bin_bin_full(
@@ -786,15 +747,13 @@ def _mxm_frontier_words(g, fw, bucketed: bool) -> jax.Array:
 
     bucketed = bucketed and part.n_buckets
     if _pallas(g):
-        from repro.kernels import common as kcommon
         from repro.kernels.spmm import ops as spmm_ops
         if bucketed:
             def local(view, f3):
                 out = jnp.zeros((view.rows + 1, t, W), jnp.uint32)
                 return view.scatter_buckets(
                     out, lambda cb, tb: spmm_ops.spmm_bin_bin_bin(
-                        _bucket_ell(cb, tb, t, part.n_cols), f3,
-                        block_k=kcommon.bucket_block_k(cb.shape[1], 4)))
+                        _bucket_ell(cb, tb, t, part.n_cols), f3))
         else:
             def local(view, f3):
                 return spmm_ops.spmm_bin_bin_bin(view.ell(part.n_cols), f3)
@@ -1035,7 +994,7 @@ def spmm_b2sr_shardmap(ell: B2SREll, x, axes, row_chunk=None):
         return core_ops.spmm_b2sr(ell_blk, x_full, row_chunk=row_chunk,
                                   vma_axes=axes)
 
-    return shard_map_compat(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None, None), P(axes), P(axes, None)),
         out_specs=P(axes, None),

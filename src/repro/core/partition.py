@@ -29,9 +29,9 @@ schedule from a partition (DESIGN.md §16): per-shard column-word bitmaps
 (which RHS words a shard's column space actually touches), the static
 per-ring-offset ``ppermute`` send/recv index sets that move only those
 words, and the output redistribution schedule that returns results as
-equal-block device-sharded global arrays. Host-side construction mirrors
-``to_ell``/``to_bucketed``; nothing here touches a mesh — placement
-happens at execution time in ``repro.core.ops_sharded``.
+equal-block device-sharded global arrays. Construction is host-side
+numpy, like ``to_ell``/``to_bucketed``; :func:`place` is the one step that
+touches a mesh (``GraphMatrix.shard`` calls it).
 """
 
 from __future__ import annotations
@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.b2sr import (B2SR, B2SREll, TILE_DIMS, _pytree, ceil_div,
-                             static_field, to_ell)
+                             ell_arrays, static_field)
 
 
 @_pytree
@@ -166,19 +166,20 @@ def partition_rows(mat: Union[B2SR, B2SREll], n_shards: int,
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    ell = mat if isinstance(mat, B2SREll) else to_ell(mat)
-    t = ell.tile_dim
+    t = mat.tile_dim
     if t not in TILE_DIMS:
         raise ValueError(f"tile_dim must be one of {TILE_DIMS}, got {t}")
-    n_tr = ell.n_tile_rows
-
-    col_g = np.asarray(ell.tile_col_idx)
-    tiles_g = np.asarray(ell.bit_tiles)
-    counts_g = np.asarray(ell.row_n_tiles)
+    n_tr = mat.n_tile_rows
+    if isinstance(mat, B2SREll):
+        col_g = np.asarray(mat.tile_col_idx)
+        tiles_g = np.asarray(mat.bit_tiles)
+        counts_g = np.asarray(mat.row_n_tiles)
+    else:               # the padded slab is built on the host, never placed
+        col_g, tiles_g, counts_g = ell_arrays(mat)
     starts = _split_starts(counts_g, n_shards, balanced)
     r_max = max(1, max(starts[p + 1] - starts[p] for p in range(n_shards)))
 
-    k = ell.max_tiles_per_row
+    k = col_g.shape[1]
     col = np.full((n_shards, r_max, k), -1, np.int32)
     tiles = np.zeros((n_shards, r_max, k, t), np.uint32)
     counts = np.zeros((n_shards, r_max), np.int32)
@@ -206,21 +207,47 @@ def partition_rows(mat: Union[B2SR, B2SREll], n_shards: int,
         if with_buckets else ((), (), ())
 
     return PartitionedB2SR(
-        tile_col_idx=jnp.asarray(col),
-        bit_tiles=jnp.asarray(tiles),
-        row_n_tiles=jnp.asarray(counts),
-        gather_idx=jnp.asarray(gidx),
+        tile_col_idx=col,
+        bit_tiles=tiles,
+        row_n_tiles=counts,
+        gather_idx=gidx,
         bucket_col_idx=buckets[0],
         bucket_bit_tiles=buckets[1],
         bucket_rows=buckets[2],
         tile_dim=t,
-        n_rows=ell.n_rows,
-        n_cols=ell.n_cols,
+        n_rows=mat.n_rows,
+        n_cols=mat.n_cols,
         n_tile_rows=n_tr,
         row_starts=tuple(int(s) for s in starts),
         shard_tiles=tuple(shard_tiles),
         cut_tiles=cut,
     )
+
+
+def place(part: PartitionedB2SR, mesh, axes: Tuple[str, ...]
+          ) -> PartitionedB2SR:
+    """Put shard ``p``'s host slabs on the devices of mesh block ``p``.
+
+    ``partition_rows`` builds the stacked ``[P, ...]`` slabs on the host;
+    here each is split along its leading axis over ``axes`` (the layout
+    the sharded rows' ``shard_map`` reads) and sent straight to its
+    devices, so no device ever holds the whole partition and no op
+    reshards it per call. The row remap is replicated.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def put(a):
+        spec = PartitionSpec(axes, *([None] * (a.ndim - 1)))
+        return jax.device_put(a, NamedSharding(mesh, spec))
+
+    return dataclasses.replace(
+        part, gather_idx=jax.device_put(
+            part.gather_idx, NamedSharding(mesh, PartitionSpec())),
+        tile_col_idx=put(part.tile_col_idx),
+        bit_tiles=put(part.bit_tiles), row_n_tiles=put(part.row_n_tiles),
+        bucket_col_idx=tuple(put(a) for a in part.bucket_col_idx),
+        bucket_bit_tiles=tuple(put(a) for a in part.bucket_bit_tiles),
+        bucket_rows=tuple(put(a) for a in part.bucket_rows))
 
 
 def _harmonised_buckets(col: np.ndarray, tiles: np.ndarray,
@@ -267,9 +294,9 @@ def _harmonised_buckets(col: np.ndarray, tiles: np.ndarray,
             c_slab[p, : local.size] = col[p, local, :k_b]
             t_slab[p, : local.size] = tiles[p, local, :k_b]
             r_slab[p, : local.size] = local
-        cols_out.append(jnp.asarray(c_slab))
-        tiles_out.append(jnp.asarray(t_slab))
-        rows_out.append(jnp.asarray(r_slab))
+        cols_out.append(c_slab)
+        tiles_out.append(t_slab)
+        rows_out.append(r_slab)
     return tuple(cols_out), tuple(tiles_out), tuple(rows_out)
 
 

@@ -27,6 +27,9 @@ import time
 from collections import OrderedDict
 from typing import Callable, Optional, Tuple
 
+import jax
+import numpy as np
+
 from repro.core.descriptor import Descriptor
 from repro.core.graphblas import GraphMatrix
 from repro.obs import cost as obs_cost
@@ -119,6 +122,45 @@ class Plan:
             _LAUNCH_LABELS).observe(time.perf_counter() - t0,
                                     **self._labels())
         return out
+
+
+class HoistedJit:
+    """``jax.jit(fn)`` that takes the arrays ``fn`` closes over as inputs.
+
+    ``jax.jit`` embeds closed-over arrays in the compiled program as
+    constants. A plan closes over its graph's slabs — gigabytes at a real
+    scale — so here the first call per argument signature traces ``fn`` to
+    a jaxpr once and compiles a program that receives the jaxpr's
+    constants as arguments; later calls pass the same device arrays.
+    """
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self._traced: dict = {}
+
+    def _resolve(self, args):
+        flat, tree = jax.tree_util.tree_flatten(args)
+        sig = (tree, tuple((np.shape(a), np.result_type(a)) for a in flat))
+        hit = self._traced.get(sig)
+        if hit is None:
+            closed, shapes = jax.make_jaxpr(self.fn, return_shape=True)(*args)
+            out_tree = jax.tree_util.tree_structure(shapes)
+
+            def run(consts, *xs):
+                out = jax.core.eval_jaxpr(closed.jaxpr, consts, *xs)
+                return jax.tree_util.tree_unflatten(out_tree, out)
+
+            hit = self._traced[sig] = (jax.jit(run), closed.consts)
+        run, consts = hit
+        return run, (consts, *flat)
+
+    def __call__(self, *args):
+        run, call_args = self._resolve(args)
+        return run(*call_args)
+
+    def lower(self, *args):
+        run, call_args = self._resolve(args)
+        return run.lower(*call_args)
 
 
 def plan_key(g: GraphMatrix, kernel: str, batch_width: int,

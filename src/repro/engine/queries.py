@@ -179,7 +179,7 @@ def _build_msbfs_plan(g: GraphMatrix, cfg: DirectionConfig):
                                                            state)
         return levels, it, trace
 
-    return jax.jit(loop)
+    return planner_mod.HoistedJit(loop)
 
 
 def msbfs(g: GraphMatrix, sources: Sequence[int],
@@ -238,7 +238,7 @@ def _build_mskhop_plan(g: GraphMatrix):
         _, visited = jax.lax.fori_loop(0, k, body, (f0, f0))
         return visited & ~f0              # exclude the sources themselves
 
-    return jax.jit(loop)
+    return planner_mod.HoistedJit(loop)
 
 
 def mskhop(g: GraphMatrix, sources: Sequence[int], k: int,
@@ -311,7 +311,7 @@ def _build_ppr_plan(g: GraphMatrix):
             cond, body, (restart, jnp.float32(jnp.inf), jnp.int32(0)))
         return pr, it
 
-    return jax.jit(loop)
+    return planner_mod.HoistedJit(loop)
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +391,7 @@ def _build_gnn_plan(g: GraphMatrix, model: GNNModel):
                 h = jax.nn.relu(h)
         return h[idx].T                           # [n_classes, s_pad]
 
-    return jax.jit(fwd)
+    return planner_mod.HoistedJit(fwd)
 
 
 def gnn_infer(g: GraphMatrix, sources: Sequence[int], model: str,

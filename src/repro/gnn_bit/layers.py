@@ -47,7 +47,8 @@ def graph_from_ell(ell: B2SREll, backend: str = "b2sr",
     """
     return GraphMatrix(
         n_rows=ell.n_rows, n_cols=ell.n_cols, nnz=-1,
-        tile_dim=ell.tile_dim, ell=ell, ell_t=None, csr=None, csr_t=None,
+        tile_dim=ell.tile_dim, tiles=None, tiles_t=None, csr=None,
+        csr_t=None, ell_cache=ell,
         backend=backend, use_buckets=use_buckets)
 
 

@@ -16,6 +16,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _or_sum(bits, axis):
+    """OR of disjoint shifted bits as a wrapping int32 sum (Mosaic has no
+    unsigned reductions), reinterpreted as uint32 words."""
+    total = jnp.sum(bits.astype(jnp.int32), axis=axis, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(total, jnp.uint32)
+
+
 def _pack_kernel(x_ref, out_ref, *, t: int, col_major: bool):
     x = x_ref[...]                                 # [BRt, BCt] 0/1
     br = x.shape[0] // t
@@ -24,9 +31,7 @@ def _pack_kernel(x_ref, out_ref, *, t: int, col_major: bool):
     if col_major:
         tiles = jnp.swapaxes(tiles, -1, -2)
     shifts = jnp.arange(t, dtype=jnp.uint32)
-    words = jnp.sum(tiles.astype(jnp.uint32) << shifts, axis=-1,
-                    dtype=jnp.uint32)              # [br, bc, t]
-    out_ref[...] = words
+    out_ref[...] = _or_sum(tiles.astype(jnp.uint32) << shifts, -1)
 
 
 def pack_dense_pallas(x, *, t: int, block_r: int = 8, block_c: int = 8,
@@ -53,7 +58,7 @@ def _pack_rows_kernel(x_ref, out_ref, *, t: int):
     br = x.shape[0] // t
     tiles = x.reshape(br, t, -1).astype(jnp.uint32)
     shifts = jnp.arange(t, dtype=jnp.uint32)[None, :, None]
-    out_ref[...] = jnp.sum(tiles << shifts, axis=1, dtype=jnp.uint32)
+    out_ref[...] = _or_sum(tiles << shifts, 1)
 
 
 def pack_rows_pallas(x, *, t: int, block_r: int = 1, block_d: int = 128,
@@ -83,7 +88,7 @@ def _transpose_kernel(w_ref, out_ref, *, t: int):
     shifts = jnp.arange(t, dtype=jnp.uint32)
     bits = (words[:, :, None] >> shifts) & jnp.uint32(1)  # [B, t, t]
     bits_t = jnp.swapaxes(bits, -1, -2)
-    out_ref[...] = jnp.sum(bits_t << shifts, axis=-1, dtype=jnp.uint32)
+    out_ref[...] = _or_sum(bits_t << shifts, -1)
 
 
 def bit_transpose_pallas(words, *, t: int, block: int = 64,

@@ -23,6 +23,9 @@ def pack_dense(x: jax.Array, t: int, col_major: bool = False,
                interpret: Optional[bool] = None) -> jax.Array:
     """Dense 0/1 [n, m] -> uint32[ceil(n/t), ceil(m/t), t] packed tiles."""
     interpret = common.interpret_default() if interpret is None else interpret
+    common.refuse_on_tpu("pack_dense", interpret,
+                         "its in-kernel 4-D tile transpose is an "
+                         "unsupported Mosaic shape cast")
     x = (x != 0).astype(jnp.uint32)
     x = common.pad_to(common.pad_to(x, 0, t), 1, t)
     return _pack(x, t, col_major, interpret)
@@ -30,7 +33,7 @@ def pack_dense(x: jax.Array, t: int, col_major: bool = False,
 
 @partial(jax.jit, static_argnames=("t", "interpret"))
 def _pack_rows(x, t, interpret):
-    return kernels.pack_rows_pallas(x, t=t, block_r=1,
+    return kernels.pack_rows_pallas(x, t=t, block_r=8,
                                     block_d=x.shape[1],
                                     interpret=interpret)
 
@@ -45,14 +48,15 @@ def pack_columns(x: jax.Array, t: int,
     per-layer activations inside their jitted forward.
     """
     interpret = common.interpret_default() if interpret is None else interpret
+    n_words = -(-x.shape[0] // t)
     x = (x != 0).astype(jnp.uint32)
-    x = common.pad_to(x, 0, t)
-    return _pack_rows(x, t, interpret)
+    x = common.pad_to(x, 0, 8 * t)
+    return _pack_rows(x, t, interpret)[:n_words]
 
 
 @partial(jax.jit, static_argnames=("t", "interpret"))
 def _transpose(words, t, interpret):
-    return kernels.bit_transpose_pallas(words, t=t, block=1,
+    return kernels.bit_transpose_pallas(words, t=t, block=8,
                                         interpret=interpret)
 
 
@@ -60,5 +64,5 @@ def bit_transpose(words: jax.Array, t: int,
                   interpret: Optional[bool] = None) -> jax.Array:
     interpret = common.interpret_default() if interpret is None else interpret
     flat = words.reshape(-1, t)
-    out = _transpose(flat, t, interpret)
-    return out.reshape(words.shape)
+    out = _transpose(common.pad_to(flat, 0, 8), t, interpret)
+    return out[: flat.shape[0]].reshape(words.shape)

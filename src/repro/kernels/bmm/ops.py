@@ -28,6 +28,9 @@ def bmm_bin_bin_sum_masked(a: B2SREll, b: B2SREll, mask: B2SREll,
     kernel needs is produced here via the word-level bit transpose (the
     conversion-time path stores it; this wrapper recomputes when absent)."""
     interpret = common.interpret_default() if interpret is None else interpret
+    common.refuse_on_tpu("bmm_bin_bin_sum_masked", interpret,
+                         "it maps the whole B operand into VMEM and gathers "
+                         "it in-kernel (ROADMAP 2.2)")
     a_col = common.pad_to(a.tile_col_idx, 0, block_r, fill=-1)
     a_tiles = common.pad_to(a.bit_tiles, 0, block_r)
     m_col = common.pad_to(mask.tile_col_idx, 0, block_r, fill=-1)

@@ -2,23 +2,19 @@
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
 from repro.core.b2sr import B2SRBucketedEll, B2SREll
-from repro.core.b2sr import or_reduce_words as or_reduce  # noqa: F401 — kernel-body alias
 
 
 def interpret_default() -> bool:
-    """Pallas kernels run in interpret mode unless a real TPU is attached.
+    """Pallas kernels compile through Mosaic on a TPU and interpret elsewhere.
 
-    CPU containers validate the kernel bodies in Python; on TPU the same
-    pallas_call lowers through Mosaic.
+    There is no override: on a TPU a kernel is interpreted only when its
+    caller passes ``interpret=True``, so a kernel that Mosaic refuses fails
+    loudly instead of quietly running in the interpreter.
     """
-    if os.environ.get("REPRO_PALLAS_INTERPRET") is not None:
-        return os.environ["REPRO_PALLAS_INTERPRET"] not in ("0", "false")
     return jax.default_backend() != "tpu"
 
 
@@ -57,12 +53,9 @@ def bucket_ell(b: B2SRBucketedEll, i: int) -> B2SREll:
     )
 
 
-def bucket_block_k(k_b: int, block_k: int) -> int:
-    """K-axis block for a bucket: its pow2-rounded width, capped at block_k.
-
-    Small buckets get grids sized by their own k_b instead of inheriting
-    the global block and re-padding hub-width work onto short rows.
-    """
-    if k_b >= block_k:
-        return block_k
-    return 1 << (k_b - 1).bit_length()
+def refuse_on_tpu(kernel: str, interpret: bool, reason: str) -> None:
+    """Raise before a kernel that Mosaic does not lower is traced on a TPU."""
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel}: no TPU (Mosaic) lowering — {reason}. Run it on the "
+            f"'b2sr' backend, or pass interpret=True explicitly.")

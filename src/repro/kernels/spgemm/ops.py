@@ -13,6 +13,10 @@ from repro.core.ops import apply_grid_mask
 from repro.kernels import common
 from repro.kernels.spgemm import spgemm as kernels
 
+#: why the SpGEMM kernel does not compile for the chip (ROADMAP 2.2)
+_NO_TPU = ("it maps the whole B operand into VMEM and gathers it in-kernel, "
+           "and its dense packed-grid output does not fit a real graph")
+
 
 @partial(jax.jit, static_argnames=("t", "n_tile_cols", "mask_mode", "block_r",
                                    "interpret"))
@@ -39,6 +43,7 @@ def mxm(a: B2SREll, b: B2SREll, mask: Optional[B2SREll] = None,
         raise ValueError(f"inner-dim mismatch: A is {a.n_rows}x{a.n_cols}, "
                          f"B is {b.n_rows}x{b.n_cols}")
     interpret = common.interpret_default() if interpret is None else interpret
+    common.refuse_on_tpu("spgemm mxm", interpret, _NO_TPU)
     t = a.tile_dim
     R = a.tile_col_idx.shape[0]
     a_col = common.pad_to(a.tile_col_idx, 0, block_r, fill=-1)

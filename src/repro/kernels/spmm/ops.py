@@ -1,4 +1,5 @@
-"""Jitted wrapper for the Pallas SpMM kernel (pad + dispatch + unpad)."""
+"""Jitted wide-RHS wrappers over the BMV kernels (dense features, packed
+activations, packed frontier matrices): one kernel pass per RHS column."""
 
 from __future__ import annotations
 
@@ -11,48 +12,59 @@ import jax.numpy as jnp
 from repro.core import ops as core_ops
 from repro.core.b2sr import B2SRBucketedEll, B2SREll, ceil_div
 from repro.kernels import common
-from repro.kernels.spmm import spmm as kernels
+from repro.kernels.bmv import bmv as kernels
+from repro.kernels.bmv import ops as bmv_ops
 
 
-@partial(jax.jit, static_argnames=("n_rows", "block_r", "block_k", "block_d",
-                                   "interpret"))
-def _spmm(col, tiles, x3, n_rows, block_r, block_k, block_d, interpret):
+def _per_column(fn, cols: jax.Array) -> jax.Array:
+    """Run ``fn`` on each column of ``cols`` in sequence -> ``[..., d]``.
+
+    One column's gathered operand is as large as the slab itself, so the
+    columns run one after another (``lax.map``) and never coexist. An
+    all-zero column maps to zeros without a pass: every row here is linear
+    with a zero identity (Σ, popcount, OR), and batches pad their source
+    axis with zero columns.
+    """
+    def one(xc):
+        zero = jax.eval_shape(fn, xc)
+        return jax.lax.cond(jnp.any(xc != 0), fn,
+                            lambda _: jnp.zeros(zero.shape, zero.dtype), xc)
+
+    return jnp.moveaxis(jax.lax.map(one, jnp.moveaxis(cols, -1, 0)), 0, -1)
+
+
+@partial(jax.jit, static_argnames=("n_rows", "interpret"))
+def _spmm(col, tiles, x3, n_rows, interpret):
     t = tiles.shape[-1]
-    out = kernels.spmm_pallas(col, tiles, x3, t=t, block_r=block_r,
-                              block_k=block_k, block_d=block_d,
-                              interpret=interpret)
-    return out.reshape(-1, out.shape[-1])[:n_rows]
+    col, tiles_w = bmv_ops.slab(col, tiles)
+
+    def one(xc):
+        out = kernels.vals_pallas(tiles_w, bmv_ops.gather_vals(col, xc), t=t,
+                                  mode="sum", interpret=interpret)
+        return out.reshape(-1)
+
+    return _per_column(one, x3)[:n_rows]
 
 
-def spmm(ell: B2SREll, x: jax.Array, block_r: int = 8, block_k: int = 4,
-         block_d: int = 128, interpret: Optional[bool] = None) -> jax.Array:
-    """Y = A @ X for dense X [n_cols, d]."""
+def spmm(ell: B2SREll, x: jax.Array,
+         interpret: Optional[bool] = None) -> jax.Array:
+    """Y = A @ X for dense X [n_cols, d] (one value-kernel pass per column)."""
     interpret = common.interpret_default() if interpret is None else interpret
     t = ell.tile_dim
     n_tc = ell.n_tile_cols
-    d = x.shape[1]
-    block_d = min(block_d, -(-d // 1))
     x_pad = jnp.pad(x, ((0, n_tc * t - x.shape[0]), (0, 0)))
-    x3 = common.pad_to(x_pad.reshape(n_tc, t, d), 2, block_d)
-    col = common.pad_to(common.pad_to(ell.tile_col_idx, 0, block_r, fill=-1),
-                        1, block_k, fill=-1)
-    tiles = common.pad_to(common.pad_to(ell.bit_tiles, 0, block_r), 1, block_k)
-    out = _spmm(col, tiles, x3, ell.n_rows, block_r, block_k, block_d,
-                interpret)
-    return out[:, :d]
+    return _spmm(ell.tile_col_idx, ell.bit_tiles,
+                 x_pad.reshape(n_tc, t, x.shape[1]), ell.n_rows, interpret)
 
 
-def spmm_bucketed(b: B2SRBucketedEll, x: jax.Array, block_r: int = 8,
-                  block_k: int = 4, block_d: int = 128,
+def spmm_bucketed(b: B2SRBucketedEll, x: jax.Array,
                   interpret: Optional[bool] = None) -> jax.Array:
-    """Y = A @ X with bucketed A: one pallas_call per bucket (k_b-sized
-    grids), feature rows scatter-merged through the row permutation."""
+    """Y = A @ X with bucketed A: one pass per bucket (k_b-sized grids),
+    feature rows scatter-merged through the row permutation."""
     d = x.shape[1]
     out = jnp.zeros((b.n_tile_rows, b.tile_dim, d), x.dtype)
     for i, rows in enumerate(b.rows):
-        e = common.bucket_ell(b, i)
-        bk = common.bucket_block_k(e.max_tiles_per_row, block_k)
-        y = spmm(e, x, block_r, bk, block_d, interpret)     # [rows_b*t, d]
+        y = spmm(common.bucket_ell(b, i), x, interpret)     # [rows_b*t, d]
         out = out.at[rows].set(y.reshape(-1, b.tile_dim, d))
     return out.reshape(-1, d)[: b.n_rows]
 
@@ -61,19 +73,20 @@ def spmm_bucketed(b: B2SRBucketedEll, x: jax.Array, block_r: int = 8,
 # Packed-RHS path: activation matrices (bin·bin→full, BitGNN layers)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("n_rows", "out_dtype", "block_r",
-                                   "block_k", "block_d", "interpret"))
-def _spmm_bbf(col, tiles, xw, n_rows, out_dtype, block_r, block_k, block_d,
-              interpret):
+@partial(jax.jit, static_argnames=("n_rows", "out_dtype", "interpret"))
+def _spmm_bbf(col, tiles, xw, n_rows, out_dtype, interpret):
     t = tiles.shape[-1]
-    out = kernels.spmm_bbf_pallas(col, tiles, xw, t=t, out_dtype=out_dtype,
-                                  block_r=block_r, block_k=block_k,
-                                  block_d=block_d, interpret=interpret)
-    return out.reshape(-1, out.shape[-1])[:n_rows]
+    col, tiles_w = bmv_ops.slab(col, tiles)
+
+    def one(words):
+        out = kernels.bits_pallas(tiles_w, bmv_ops.gather_words(col, words),
+                                  t=t, count=True, interpret=interpret)
+        return out.reshape(-1)
+
+    return _per_column(one, xw)[:n_rows].astype(out_dtype)
 
 
 def spmm_bin_bin_full(ell: B2SREll, xw: jax.Array, out_dtype=jnp.float32,
-                      block_r: int = 8, block_k: int = 4, block_d: int = 128,
                       interpret: Optional[bool] = None) -> jax.Array:
     """BitGNN aggregation: packed adjacency × BitMatrix words → dense counts.
 
@@ -82,28 +95,18 @@ def spmm_bin_bin_full(ell: B2SREll, xw: jax.Array, out_dtype=jnp.float32,
     accumulation, never an unpack-and-matmul.
     """
     interpret = common.interpret_default() if interpret is None else interpret
-    d = xw.shape[1]
-    block_d = min(block_d, d)
-    xw_pad = common.pad_to(xw, 1, block_d)
-    col = common.pad_to(common.pad_to(ell.tile_col_idx, 0, block_r, fill=-1),
-                        1, block_k, fill=-1)
-    tiles = common.pad_to(common.pad_to(ell.bit_tiles, 0, block_r), 1, block_k)
-    out = _spmm_bbf(col, tiles, xw_pad, ell.n_rows, jnp.dtype(out_dtype),
-                    block_r, block_k, block_d, interpret)
-    return out[:, :d]
+    return _spmm_bbf(ell.tile_col_idx, ell.bit_tiles, xw, ell.n_rows,
+                     jnp.dtype(out_dtype), interpret)
 
 
 def spmm_bin_bin_full_bucketed(b: B2SRBucketedEll, xw: jax.Array,
-                               out_dtype=jnp.float32, block_r: int = 8,
-                               block_k: int = 4, block_d: int = 128,
+                               out_dtype=jnp.float32,
                                interpret: Optional[bool] = None) -> jax.Array:
     """Bucketed BitGNN aggregation: per-bucket k_b grids, scatter-merged."""
     d = xw.shape[1]
     out = jnp.zeros((b.n_tile_rows, b.tile_dim, d), out_dtype)
     for i, rows in enumerate(b.rows):
-        e = common.bucket_ell(b, i)
-        bk = common.bucket_block_k(e.max_tiles_per_row, block_k)
-        y = spmm_bin_bin_full(e, xw, out_dtype, block_r, bk, block_d,
+        y = spmm_bin_bin_full(common.bucket_ell(b, i), xw, out_dtype,
                               interpret)
         out = out.at[rows].set(y.reshape(-1, b.tile_dim, d))
     return out.reshape(-1, d)[: b.n_rows]
@@ -113,55 +116,51 @@ def spmm_bin_bin_full_bucketed(b: B2SRBucketedEll, xw: jax.Array,
 # Packed-RHS path: frontier matrices (bin·bin→bin with a wide RHS, engine/)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("n_tile_rows", "complement", "block_r",
-                                   "block_k", "interpret"))
-def _spmm_bbb(col, tiles, f3, mask, n_tile_rows, complement, block_r, block_k,
-              interpret):
+@partial(jax.jit, static_argnames=("interpret",))
+def _spmm_bbb(col, tiles, f3, interpret):
     t = tiles.shape[-1]
-    mask_pad = None if mask is None else common.pad_to(mask, 0, block_r)
-    out = kernels.spmm_bbb_pallas(col, tiles, f3, mask_pad, t=t,
-                                  complement=complement, block_r=block_r,
-                                  block_k=block_k, interpret=interpret)
-    return out[:n_tile_rows]
+    n_tile_rows = col.shape[0]
+    col, tiles_w = bmv_ops.slab(col, tiles)
+
+    def one(fw):
+        out = kernels.vals_pallas(tiles_w, bmv_ops.gather_vals(col, fw), t=t,
+                                  mode="or", ident=0, interpret=interpret)
+        return out.reshape(-1, t)
+
+    return _per_column(one, f3)[:n_tile_rows]
 
 
 def spmm_bin_bin_bin(ell: B2SREll, f_packed: jax.Array,
                      mask_packed: Optional[jax.Array] = None,
-                     complement: bool = True, block_r: int = 8,
-                     block_k: int = 4,
+                     complement: bool = True,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Multi-frontier boolean traversal: packed frontier matrix in/out.
 
     ``f_packed``: ``uint32[n_tile_cols, t, W]`` (``pack_frontier_matrix``);
-    returns ``uint32[n_tile_rows, t, W]``. The §V mask (per-source visited
-    sets, output layout) is ANDed in-kernel at the last K step; unmasked
-    calls compile the maskless kernel variant (no mask load, no AND pass).
+    returns ``uint32[n_tile_rows, t, W]``. Each source word column is one
+    ∨.∧ value-kernel pass (32 sources per pass); the §V mask (per-source
+    visited sets, output layout) is ANDed onto the result.
     """
     interpret = common.interpret_default() if interpret is None else interpret
-    t = ell.tile_dim
-    n_tr = ceil_div(ell.n_rows, t)
-    col = common.pad_to(common.pad_to(ell.tile_col_idx, 0, block_r, fill=-1),
-                        1, block_k, fill=-1)
-    tiles = common.pad_to(common.pad_to(ell.bit_tiles, 0, block_r), 1, block_k)
-    return _spmm_bbb(col, tiles, f_packed, mask_packed, n_tr, complement,
-                     block_r, block_k, interpret)
+    out = _spmm_bbb(ell.tile_col_idx, ell.bit_tiles, f_packed, interpret)
+    out = out[: ceil_div(ell.n_rows, ell.tile_dim)]
+    if mask_packed is not None:
+        out = core_ops.apply_frontier_mask(out, mask_packed, complement)
+    return out
 
 
 def spmm_bin_bin_bin_bucketed(b: B2SRBucketedEll, f_packed: jax.Array,
                               mask_packed: Optional[jax.Array] = None,
-                              complement: bool = True, block_r: int = 8,
-                              block_k: int = 4,
+                              complement: bool = True,
                               interpret: Optional[bool] = None) -> jax.Array:
-    """Bucketed multi-frontier traversal: one pallas_call per bucket slab,
+    """Bucketed multi-frontier traversal: one pass per bucket slab,
     scatter-merged; the mask is ANDed after the merge (still pre-store, §V)."""
     out = jnp.zeros((b.n_tile_rows, b.tile_dim, f_packed.shape[2]),
                     jnp.uint32)
     for i, rows in enumerate(b.rows):
-        e = common.bucket_ell(b, i)
-        bk = common.bucket_block_k(e.max_tiles_per_row, block_k)
-        words = spmm_bin_bin_bin(e, f_packed, None, True, block_r, bk,
-                                 interpret)
-        out = out.at[rows].set(words)
+        out = out.at[rows].set(spmm_bin_bin_bin(common.bucket_ell(b, i),
+                                                f_packed,
+                                                interpret=interpret))
     if mask_packed is not None:
         out = core_ops.apply_frontier_mask(out, mask_packed, complement)
     return out

@@ -52,7 +52,28 @@ def make_debug_mesh(n_devices: int | None = None, model: int = 2):
     return jax.make_mesh((n // model, model), ("data", "model"))
 
 
-# Hardware constants for the roofline model (TPU v5e per chip)
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_LINK_BW = 50e9                # B/s per link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (system architecture):
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+#: of inter-chip interconnect (4 links of 50 GB/s).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,      # FLOP/s
+        "int8_ops": 393e12,        # OP/s
+        "hbm_bytes": 16e9,         # B
+        "hbm_bw": 819e9,           # B/s
+        "ici_links": 4,
+        "ici_link_bw": 50e9,       # B/s per link
+    },
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The peak table row for ``device_kind``; an unknown device is an
+    error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(DEVICE_PEAKS)}"
+                       ) from None

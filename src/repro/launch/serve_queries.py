@@ -62,8 +62,10 @@ def main() -> None:
                               QueryRejected, ServerConfig)
     from repro.obs import cost as obs_cost
     from repro.obs import export as obs_export
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import metrics as obs_metrics
 
+    enable_compile_cache()
     if args.cost_accounting:
         obs_cost.set_cost_accounting(True)
 

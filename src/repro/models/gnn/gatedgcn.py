@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import nn
-from repro.core.ops import shard_map_compat
 from repro.configs.base import GNNConfig
 from repro.models.gnn.common import GraphBatch, node_ce_loss
 
@@ -112,7 +111,7 @@ def _layer_agg_shardmap(lp, h, e, batch, cfg, n):
     espec = P(axes, None)
     mspec = P(axes)
     lp_specs = jax.tree_util.tree_map(lambda _: P(), lp)
-    return shard_map_compat(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(lp_specs, nspec, espec, mspec, mspec, mspec),
         out_specs=(espec, nspec, nspec),

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import nn
-from repro.core.ops import shard_map_compat
 
 Params = Dict[str, Any]
 
@@ -220,7 +219,7 @@ def _moe_ffn_shardmap(p: Params, x: jax.Array, cfg):
         return y.reshape(B_loc, S, d), aux
 
     xin = jax.lax.with_sharding_constraint(x, P(data_axes, None, None))
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), P(data_axes, None, None)),

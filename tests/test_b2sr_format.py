@@ -124,3 +124,91 @@ def test_pack_dense_tiles_matches_converter():
             for k in range(ell.max_tiles_per_row):
                 if col[i, k] >= 0:
                     assert np.array_equal(tiles[i, k], words[i, col[i, k]])
+
+
+# ---------------------------------------------------------------------------
+# construction at scale: lazy ELL, bucketing from the tile CSR, word-level
+# transpose — each pinned bit-exact against a direct (unpacking) oracle
+# ---------------------------------------------------------------------------
+
+def _construction_graphs():
+    from repro.data import graphs as G
+    return {"rmat": G.rmat_graph(300, avg_degree=6, seed=3),
+            "diagonal": G.diagonal_graph(300, bandwidth=4, seed=3)}
+
+
+def _ell_oracle(mat):
+    """The single-max ELL by a per-row loop over the tile CSR."""
+    ptr = np.asarray(mat.tile_row_ptr)
+    k = max(int(np.diff(ptr).max()), 1)
+    col = np.full((mat.n_tile_rows, k), -1, np.int32)
+    tiles = np.zeros((mat.n_tile_rows, k, mat.tile_dim), np.uint32)
+    for i in range(mat.n_tile_rows):
+        s, e = ptr[i], ptr[i + 1]
+        col[i, :e - s] = mat.tile_col_idx[s:e]
+        tiles[i, :e - s] = mat.bit_tiles[s:e]
+    return col, tiles
+
+
+@pytest.mark.parametrize("t", (8, 32))
+@pytest.mark.parametrize("kind", ("rmat", "diagonal"))
+def test_lazy_ell_matches_eager_build(kind, t):
+    from repro.core import GraphMatrix
+    r, c = _construction_graphs()[kind]
+    g = GraphMatrix.from_coo(r, c, 300, 300, tile_dim=t)
+    assert g.ell_cache is None and g.ell_t_cache is None
+    col, tiles = _ell_oracle(coo_to_b2sr(r, c, 300, 300, t))
+    assert np.array_equal(np.asarray(g.ell.tile_col_idx), col)
+    assert np.array_equal(np.asarray(g.ell.bit_tiles), tiles)
+    col_t, tiles_t = _ell_oracle(coo_to_b2sr(c, r, 300, 300, t))
+    assert np.array_equal(np.asarray(g.ell_t.tile_col_idx), col_t)
+    assert np.array_equal(np.asarray(g.ell_t.bit_tiles), tiles_t)
+
+
+@pytest.mark.parametrize("t", (8, 32))
+@pytest.mark.parametrize("kind", ("rmat", "diagonal"))
+@pytest.mark.parametrize("max_buckets", (1, 2, 8))
+def test_bucketing_from_tile_csr(kind, t, max_buckets):
+    from repro.core import to_bucketed
+    r, c = _construction_graphs()[kind]
+    mat = coo_to_b2sr(r, c, 300, 300, t)
+    col, tiles = _ell_oracle(mat)
+    counts = np.diff(np.asarray(mat.tile_row_ptr))
+    bk = to_bucketed(mat, max_buckets=max_buckets)
+    assert bk.n_buckets <= max_buckets
+    ranges = np.ceil(np.log2(np.maximum(counts, 1))).astype(int)
+    uniq = np.unique(ranges[counts > 0])
+    widest = []
+    for cb, tb, rows in zip(bk.col_idx, bk.bit_tiles, bk.rows):
+        rows = np.asarray(rows)
+        k_b = cb.shape[1]
+        # each slab is exactly the left-justified rows of the ELL view
+        assert np.array_equal(np.asarray(cb), col[rows, :k_b])
+        assert np.array_equal(np.asarray(tb), tiles[rows, :k_b])
+        assert k_b == counts[rows].max()
+        widest.append(ranges[rows].max())
+    # the cap merges the narrowest ranges: every range above the merged
+    # one keeps a slab of its own
+    keep = uniq[max(uniq.size - max_buckets, 0) + 1:] if uniq.size > \
+        max_buckets else uniq
+    assert set(keep.tolist()) <= set(widest)
+    assert bk.real_words() == mat.n_tiles
+
+
+@pytest.mark.parametrize("t", (8, 32))
+@pytest.mark.parametrize("kind", ("rmat", "diagonal"))
+def test_word_level_transpose_matches_repack(kind, t):
+    from repro.core.b2sr import bit_transpose_np
+    r, c = _construction_graphs()[kind]
+    mat = coo_to_b2sr(r, c, 300, 300, t)
+    got = transpose(mat)
+    want = coo_to_b2sr(c, r, 300, 300, t)           # repack of swapped COO
+    for f in ("tile_row_ptr", "tile_col_idx", "bit_tiles"):
+        assert np.array_equal(np.asarray(getattr(got, f)),
+                              np.asarray(getattr(want, f))), f
+    # the block-swap transpose against the unpack/swap/repack formula
+    words = np.asarray(mat.bit_tiles)
+    bits = (words[:, :, None] >> np.arange(t, dtype=np.uint32)) & 1
+    oracle = (np.swapaxes(bits, 1, 2) << np.arange(t, dtype=np.uint32)).sum(
+        axis=-1, dtype=np.uint32)
+    assert np.array_equal(bit_transpose_np(words, t), oracle)

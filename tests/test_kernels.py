@@ -90,7 +90,7 @@ def test_spmm_kernel(t, n, d_feat):
     ell = to_ell(dense_to_b2sr(d, t))
     rng = np.random.default_rng(4)
     X = jnp.asarray(rng.standard_normal((n, d_feat)).astype(np.float32))
-    got = spmm_ops.spmm(ell, X, block_d=16)
+    got = spmm_ops.spmm(ell, X)
     want = spmm_ref.spmm(ell, X)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

@@ -114,14 +114,13 @@ _COMPRESSION_SCRIPT = textwrap.dedent("""
         return compressed_psum(x, "dp", e)
 
     with mesh:
-        from repro.core.ops import shard_map_compat
-        ref = shard_map_compat(exact, mesh=mesh, in_specs=P("dp", None),
+        ref = jax.shard_map(exact, mesh=mesh, in_specs=P("dp", None),
                             out_specs=P("dp", None))(g)[0]
         e = jnp.zeros((8, 256))
         total_err = []
         # error feedback: residual carried across steps shrinks the bias
         for _ in range(4):
-            s, e = shard_map_compat(approx, mesh=mesh,
+            s, e = jax.shard_map(approx, mesh=mesh,
                                  in_specs=(P("dp", None), P("dp", None)),
                                  out_specs=(P("dp", None), P("dp", None)))(g, e)
             total_err.append(float(jnp.max(jnp.abs(s[0] - ref))))
