@@ -84,7 +84,7 @@ def test_occupancy_monotone_tile_effects():
 
 @given(st.integers(1, 80), st.integers(1, 80),
        st.sampled_from(TILE_DIMS), st.integers(0, 10_000))
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25, deadline=None, derandomize=True)
 def test_roundtrip_property(n, m, t, seed):
     d = random_dense(n, m, 0.15, seed)
     mat = dense_to_b2sr(d, t)
@@ -92,7 +92,7 @@ def test_roundtrip_property(n, m, t, seed):
 
 
 @given(st.sampled_from(TILE_DIMS), st.integers(0, 1000))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20, deadline=None, derandomize=True)
 def test_bit_transpose_involution(t, seed):
     rng = np.random.default_rng(seed)
     words = jnp.asarray(
@@ -102,7 +102,7 @@ def test_bit_transpose_involution(t, seed):
 
 
 @given(st.sampled_from(TILE_DIMS), st.integers(1, 200), st.integers(0, 999))
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20, deadline=None, derandomize=True)
 def test_pack_unpack_vector(t, n, seed):
     rng = np.random.default_rng(seed)
     x = (rng.random(n) < 0.4)

@@ -15,6 +15,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from conftest import SCRIPT_TIMEOUT_S
+
 ROOT = Path(__file__).resolve().parents[1]
 
 
@@ -63,7 +65,7 @@ def test_refuses_without_tpu():
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     out = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
                          env=env, capture_output=True, text=True,
-                         timeout=120)
+                         timeout=SCRIPT_TIMEOUT_S)
     assert out.returncode != 0
     assert "no TPU" in out.stderr
     assert '"ok"' not in out.stdout

@@ -23,9 +23,6 @@ Layout:
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
@@ -34,7 +31,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from conftest import optional_hypothesis  # noqa: E402
+from conftest import (  # noqa: E402
+    assert_marker, optional_hypothesis, run_script)
 
 from repro.algorithms import direction as direction_mod  # noqa: E402
 from repro.algorithms.bfs import BFSResult, bfs  # noqa: E402
@@ -408,14 +406,9 @@ MARKERS = ["BFS_SHARDED_OK", "MSBFS_SHARDED_OK", "CC_SHARDED_OK"]
 
 @pytest.fixture(scope="module")
 def sharded_direction_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=900, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", MARKERS)
 def test_sharded_direction_parity(sharded_direction_run, marker):
-    assert sharded_direction_run.returncode == 0, \
-        sharded_direction_run.stderr[-4000:]
-    assert marker in sharded_direction_run.stdout
+    assert_marker(sharded_direction_run, marker)

@@ -2,12 +2,11 @@
 another (the node-failure / rescale recovery path). Subprocess with 8
 devices: save sharded over 8, restore sharded over 4 and over 2×2."""
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import pytest
+
+from conftest import assert_marker, run_script
 
 _SCRIPT = textwrap.dedent("""
     import os, tempfile
@@ -57,13 +56,9 @@ _SCRIPT = textwrap.dedent("""
 
 @pytest.fixture(scope="module")
 def subprocess_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", ["RESHARD_4_OK", "RESHARD_2x2_OK"])
 def test_elastic_reshard(subprocess_run, marker):
-    assert subprocess_run.returncode == 0, subprocess_run.stderr[-2500:]
-    assert marker in subprocess_run.stdout
+    assert_marker(subprocess_run, marker)

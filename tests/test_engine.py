@@ -1,13 +1,14 @@
 """Batched multi-source query engine: parity, planner, batcher (DESIGN.md §9).
 
 The engine is a pure batching transform — every multi-source result must
-equal a loop of the single-source algorithm: bit-exact for BFS/k-hop/SSSP
-(boolean/integer ops), allclose for PPR (the multi-vector spmm sums in a
-different float order than the scanned bmv). Pinned across tile dims
-4/8/16/32, all three backends, bucketed on/off, and ragged batch sizes
-(1, word-width, non-pow2, > 32 sources). Plus: the packed frontier-matrix
-scheme itself, the plan cache (hit/miss/eviction), the request batcher,
-and the GraphMatrix memoization satellites.
+equal a loop of single-source runs (for BFS, a plain numpy BFS over the
+dense adjacency): bit-exact for BFS/k-hop/SSSP (boolean/integer ops),
+allclose for PPR (the multi-vector spmm sums in a different float order
+than the scanned bmv). Pinned across tile dims 4/8/16/32, all three
+backends, bucketed on/off, and ragged batch sizes (1, word-width,
+non-pow2, > 32 sources). Plus: the packed frontier-matrix scheme itself,
+the plan cache (hit/miss/eviction), the request batcher, and the
+GraphMatrix memoization satellites.
 """
 
 import numpy as np
@@ -119,13 +120,27 @@ def test_pallas_spmm_bbb_matches_jnp_and_ref(t):
 # multi-source parity vs looped single-source runs
 # ---------------------------------------------------------------------------
 
+def reference_levels(g, source):
+    """Hop levels by a plain numpy BFS over the dense adjacency."""
+    adj = np.zeros((g.n_rows, g.n_cols), bool)
+    adj[np.asarray(g.csr.row_idx), np.asarray(g.csr.col_idx)] = True
+    levels = np.full(g.n_rows, -1, np.int32)
+    levels[source] = 0
+    frontier, depth = levels == 0, 0
+    while frontier.any():
+        depth += 1
+        frontier = adj[frontier].any(axis=0) & (levels < 0)
+        levels[frontier] = depth
+    return levels
+
+
 def assert_msbfs_matches(g, sources):
     res = msbfs(g, sources)
     assert res.levels.shape == (g.n_rows, len(sources))
+    want = {s: reference_levels(g, s) for s in map(int, sources)}
     for i, s in enumerate(sources):
-        want = bfs(g, int(s)).levels
         assert np.array_equal(np.asarray(res.levels[:, i]),
-                              np.asarray(want)), f"source {s}"
+                              want[int(s)]), f"source {s}"
 
 
 @pytest.mark.parametrize("t", TILE_DIMS)

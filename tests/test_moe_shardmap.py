@@ -5,12 +5,11 @@ strategies must agree exactly (the only semantic difference is local vs
 global overflow accounting). Runs in a subprocess with 8 forced devices.
 """
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import pytest
+
+from conftest import assert_marker, run_script
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -22,7 +21,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs.base import MoEConfig, TransformerConfig
     from repro.models import moe as moe_mod
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = TransformerConfig(
         name="t", n_layers=1, d_model=32, n_heads=4, n_kv_heads=2,
         head_dim=8, d_ff=64, vocab_size=64,
@@ -61,13 +61,9 @@ _SCRIPT = textwrap.dedent("""
 
 @pytest.fixture(scope="module")
 def subprocess_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=420, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", ["MOE_OK", "GRAD_OK"])
 def test_moe_shardmap_matches_dense(subprocess_run, marker):
-    assert subprocess_run.returncode == 0, subprocess_run.stderr[-3000:]
-    assert marker in subprocess_run.stdout
+    assert_marker(subprocess_run, marker)

@@ -11,14 +11,12 @@ forced host devices (the dry-run-only rule for device forcing), using
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
 import pytest
 
+from conftest import assert_marker, run_script
 from repro.core import partition as pm
 from repro.core.b2sr import b2sr_to_dense, coo_to_b2sr, to_ell
 
@@ -206,39 +204,62 @@ _SCRIPT = textwrap.dedent("""
         d[seed % n] |= (rng.random(n) < 0.6)
         return GraphMatrix.from_dense(d, tile_dim=t), d
 
-    # --- every kernel row, all tile dims, buckets on/off ------------------
-    for t in (4, 8, 16, 32):
+    # --- every kernel row, buckets on/off ---------------------------------
+    # A tile dim changes the packing, not which shard_map twin runs: each
+    # (row, buckets) pair runs once, and the dims rotate across the rows
+    # so that 4, 8, 16 and 32 all run sharded.
+    TILE_DIMS = (4, 8, 16, 32)
+    cases = {}
+    for t in TILE_DIMS:
         g, d = build(96, t, seed=t)
-        gs = g.shard(mesh)
         rng = np.random.RandomState(100 + t)
-        x = jnp.asarray(rng.rand(96).astype(np.float32))
-        bv = BitVector.pack(jnp.asarray(rng.rand(96) > 0.5), t)
-        fb = FrontierBatch.pack(jnp.asarray(rng.rand(96, 5) > 0.5), t)
-        X = jnp.asarray(rng.rand(96, 6).astype(np.float32))
-        for ub in (True, False):
-            a, b = g.with_buckets(ub), gs.with_buckets(ub)
-            assert np.array_equal(np.asarray(b.mxv(bv).words),
-                                  np.asarray(a.mxv(bv).words))
-            assert np.array_equal(
-                np.asarray(b.mxv(bv, ARITHMETIC, out_dtype=jnp.int32)),
-                np.asarray(a.mxv(bv, ARITHMETIC, out_dtype=jnp.int32)))
-            # float ⊕ rows: same per-row reduction order, but allow for
-            # shape-dependent XLA lowering; bit-level rows stay bit-exact
-            assert np.allclose(np.asarray(b.mxv(x)), np.asarray(a.mxv(x)),
-                               atol=1e-6)
-            assert np.array_equal(np.asarray(b.mxv(x, MIN_PLUS)),
-                                  np.asarray(a.mxv(x, MIN_PLUS)))
-            assert np.allclose(np.asarray(b.mxm(X)), np.asarray(a.mxm(X)),
-                               atol=1e-5)
-            assert np.array_equal(np.asarray(b.mxm(fb).words),
-                                  np.asarray(a.mxm(fb).words))
-        # SpGEMM rows (bin + count) and the fused tri reduction
-        pa, pb = g.mxm(g), gs.mxm(g)
-        assert pa.nnz == pb.nnz
-        assert np.array_equal(np.asarray(pa.csr.col_idx),
-                              np.asarray(pb.csr.col_idx))
-        assert np.array_equal(np.asarray(gs.mxm(g, ARITHMETIC)),
-                              np.asarray(g.mxm(g, ARITHMETIC)))
+        cases[t] = (g, g.shard(mesh), dict(
+            x=jnp.asarray(rng.rand(96).astype(np.float32)),
+            bv=BitVector.pack(jnp.asarray(rng.rand(96) > 0.5), t),
+            fb=FrontierBatch.pack(jnp.asarray(rng.rand(96, 5) > 0.5), t),
+            X=jnp.asarray(rng.rand(96, 6).astype(np.float32))))
+
+    def mxv_bin(a, b, o):
+        assert np.array_equal(np.asarray(b.mxv(o["bv"]).words),
+                              np.asarray(a.mxv(o["bv"]).words))
+
+    def mxv_count(a, b, o):
+        assert np.array_equal(
+            np.asarray(b.mxv(o["bv"], ARITHMETIC, out_dtype=jnp.int32)),
+            np.asarray(a.mxv(o["bv"], ARITHMETIC, out_dtype=jnp.int32)))
+
+    # float ⊕ rows: same per-row reduction order, but allow for
+    # shape-dependent XLA lowering; bit-level rows stay bit-exact
+    def mxv_float(a, b, o):
+        assert np.allclose(np.asarray(b.mxv(o["x"])),
+                           np.asarray(a.mxv(o["x"])), atol=1e-6)
+
+    def mxv_min_plus(a, b, o):
+        assert np.array_equal(np.asarray(b.mxv(o["x"], MIN_PLUS)),
+                              np.asarray(a.mxv(o["x"], MIN_PLUS)))
+
+    def mxm_float(a, b, o):
+        assert np.allclose(np.asarray(b.mxm(o["X"])),
+                           np.asarray(a.mxm(o["X"])), atol=1e-5)
+
+    def mxm_frontier(a, b, o):
+        assert np.array_equal(np.asarray(b.mxm(o["fb"]).words),
+                              np.asarray(a.mxm(o["fb"]).words))
+
+    ROWS = (mxv_bin, mxv_count, mxv_float, mxv_min_plus, mxm_float,
+            mxm_frontier)
+    for ub in (True, False):
+        for i, row in enumerate(ROWS):
+            g, gs, o = cases[TILE_DIMS[(i + 2 * ub) % len(TILE_DIMS)]]
+            row(g.with_buckets(ub), gs.with_buckets(ub), o)
+    # SpGEMM rows (bin + count) and the fused tri reduction
+    g, gs, _ = cases[16]
+    pa, pb = g.mxm(g), gs.mxm(g)
+    assert pa.nnz == pb.nnz
+    assert np.array_equal(np.asarray(pa.csr.col_idx),
+                          np.asarray(pb.csr.col_idx))
+    assert np.array_equal(np.asarray(gs.mxm(g, ARITHMETIC)),
+                          np.asarray(g.mxm(g, ARITHMETIC)))
     print("ROWS_OK")
 
     # --- masked + transposed descriptors ----------------------------------
@@ -333,14 +354,9 @@ MARKERS = ["ROWS_OK", "DESC_OK", "ALGOS_OK", "ENGINE_OK", "GUARDS_OK"]
 
 @pytest.fixture(scope="module")
 def sharded_parity_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=900, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", MARKERS)
 def test_sharded_parity(sharded_parity_run, marker):
-    assert sharded_parity_run.returncode == 0, \
-        sharded_parity_run.stderr[-4000:]
-    assert marker in sharded_parity_run.stdout
+    assert_marker(sharded_parity_run, marker)

@@ -10,14 +10,12 @@ dedup, idempotent failure handles, and the restart-safe warmup
 round-trip.
 """
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
 import pytest
 
+from conftest import assert_marker, run_script
 from repro.algorithms import bfs, khop_frontier, sssp
 from repro.core import GraphMatrix, dispatch
 from repro.engine import (CircuitBreaker, FaultInjector, GraphQueryServer,
@@ -477,14 +475,9 @@ _SHARD_MARKERS = ["SHARD_B2SR_OK", "SHARD_CSR_OK", "SHARD_WARM_OK"]
 
 @pytest.fixture(scope="module")
 def sharded_serving_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SHARD_SCRIPT], capture_output=True,
-        text=True, timeout=900, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SHARD_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", _SHARD_MARKERS)
 def test_sharded_fallback_parity(sharded_serving_run, marker):
-    assert sharded_serving_run.returncode == 0, \
-        sharded_serving_run.stderr[-4000:]
-    assert marker in sharded_serving_run.stdout
+    assert_marker(sharded_serving_run, marker)

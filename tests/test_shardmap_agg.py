@@ -10,12 +10,11 @@ per block to a common count, so edge-shard i contains exactly the edges
 whose receivers live in node-block i.
 """
 
-import os
-import subprocess
-import sys
 import textwrap
 
 import pytest
+
+from conftest import assert_marker, run_script
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -32,7 +31,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.models.gnn.common import GraphBatch
 
     P_SHARDS = 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     N, t = 256, 8
     n_local = N // P_SHARDS
     rng = np.random.default_rng(0)
@@ -96,13 +96,9 @@ _SCRIPT = textwrap.dedent("""
 
 @pytest.fixture(scope="module")
 def subprocess_run():
-    return subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,
-        timeout=420, env={**os.environ, "PYTHONPATH": "src"},
-    )
+    return run_script(_SCRIPT)
 
 
 @pytest.mark.parametrize("marker", ["GATEDGCN_OK", "SPMM_OK"])
 def test_shardmap_matches_dense(subprocess_run, marker):
-    assert subprocess_run.returncode == 0, subprocess_run.stderr[-3000:]
-    assert marker in subprocess_run.stdout
+    assert_marker(subprocess_run, marker)

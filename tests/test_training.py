@@ -2,8 +2,6 @@
 
 import itertools
 import os
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -11,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import assert_marker, run_script
 from repro.configs.base import GNNConfig
 from repro.data.synthetic import full_graph_batch
 from repro.models.gnn import gcn
@@ -104,7 +103,8 @@ _COMPRESSION_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.training.compression import compressed_psum
 
-    mesh = jax.make_mesh((8,), ("dp",))
+    mesh = jax.make_mesh((8,), ("dp",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 256))
 
     def exact(x):
@@ -131,8 +131,4 @@ _COMPRESSION_SCRIPT = textwrap.dedent("""
 
 
 def test_compressed_psum_close_to_exact():
-    r = subprocess.run([sys.executable, "-c", _COMPRESSION_SCRIPT],
-                       capture_output=True, text=True, timeout=300,
-                       env={**os.environ, "PYTHONPATH": "src"})
-    assert r.returncode == 0, r.stderr[-2500:]
-    assert "COMPRESS_OK" in r.stdout
+    assert_marker(run_script(_COMPRESSION_SCRIPT), "COMPRESS_OK")
