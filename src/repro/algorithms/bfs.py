@@ -13,6 +13,12 @@ switching traversal stays one compiled ``while_loop``.
 The frontier, visited set, and mask are bit-packed uint32 words end-to-end
 on the b2sr backends; levels are materialised incrementally in an int32
 vector.
+
+The single-source loop is compiled once per graph and option set
+(``direction`` config and ``row_chunk``) and kept on the graph
+(``GraphMatrix.loop_plan``): ``source`` and ``max_iters`` are traced
+``int32`` arguments, so later calls dispatch the resident executable
+without tracing or lowering anything.
 """
 
 from __future__ import annotations
@@ -84,16 +90,26 @@ def bfs(g: GraphMatrix, source, max_iters: Optional[int] = None,
         from repro.engine.queries import msbfs
         return msbfs(g, source, max_iters=max_iters, direction=cfg)
     source = int(source)
+    plan = g.loop_plan("bfs", (cfg, row_chunk),
+                       lambda: _build_bfs_plan(g, cfg, row_chunk))
+    levels, it, trace = plan(np.int32(source), np.int32(max_iters))
+    it = int(it)
+    dirs = direction_mod.trace_tuple(trace, it)
+    direction_mod.observe_trace(dirs, kernel="bfs")
+    return BFSResult(levels=levels, n_iterations=it, directions=dirs)
+
+
+def _build_bfs_plan(g: GraphMatrix, cfg: DirectionConfig,
+                    row_chunk: Optional[int]):
+    """The single-source traversal as one program of traced
+    ``(source, max_iters)``; ``cfg`` and ``row_chunk`` are baked in."""
+    from repro.engine.planner import HoistedJit
+    n = g.n_rows
     t = g.tile_dim
     # both directions traverse Aᵀ · frontier over the stored transpose;
     # push/pull differ in schedule (and kernel), never in the operand
     gt = g.transposed()
     avg_degree = g.nnz / max(n, 1)
-
-    src = jnp.zeros(n, jnp.float32).at[source].set(1.0)
-    frontier = BitVector.pack(src, t, n)
-    visited = frontier
-    levels = jnp.full(n, -1, jnp.int32).at[source].set(0)
 
     def step_push(f, v):
         return gt.mxv(f, desc=Descriptor(mask=v, complement=True,
@@ -104,38 +120,48 @@ def bfs(g: GraphMatrix, source, max_iters: Optional[int] = None,
                                          row_chunk=row_chunk,
                                          direction="pull"))
 
-    def cond(state):
-        # NOT jnp.sum(frontier.astype(uint64)): without x64 that silently
-        # downcasts to uint32 and the word sum can wrap to exactly zero,
-        # terminating BFS with a live frontier. any() is also cheaper.
-        frontier, _, _, it, _, _, _ = state
-        return frontier.any() & (it < max_iters)
+    def loop(source, max_iters):
+        src = jnp.zeros(n, jnp.float32).at[source].set(1.0)
+        frontier = BitVector.pack(src, t, n)
+        levels = jnp.full(n, -1, jnp.int32).at[source].set(0)
 
-    def body(state):
-        frontier, visited, levels, it, d, locked, trace = state
-        if cfg.mode == "auto":
-            # direction is loop-carried traced state — both branches are
-            # compiled once, the switch costs one predicate per iteration
-            nxt = jax.lax.cond(d == direction_mod.PULL, step_pull,
-                               step_push, frontier, visited)
-        elif cfg.mode == "pull":
-            nxt = step_pull(frontier, visited)
-        else:
-            nxt = step_push(frontier, visited)
-        new_visited = visited | nxt
-        new_bits = nxt.unpack(jnp.int32)
-        levels_new = jnp.where((new_bits > 0) & (levels < 0), it + 1, levels)
-        trace = direction_mod.record(trace, it, d)
-        d_next, locked = direction_mod.next_direction(
-            cfg, d, locked, direction_mod.nnz_words(nxt.words),
-            direction_mod.nnz_words(new_visited.words), n, avg_degree)
-        return (nxt, new_visited, levels_new, it + 1, d_next, locked, trace)
+        def cond(state):
+            # NOT jnp.sum(frontier.astype(uint64)): without x64 that
+            # silently downcasts to uint32 and the word sum can wrap to
+            # exactly zero, terminating BFS with a live frontier. any() is
+            # also cheaper.
+            frontier, _, _, it, _, _, _ = state
+            return frontier.any() & (it < max_iters)
 
-    state = (frontier, visited, levels, jnp.int32(0),
-             direction_mod.initial_direction(cfg), jnp.bool_(False),
-             direction_mod.empty_trace(max_iters))
-    _, _, levels, it, _, _, trace = jax.lax.while_loop(cond, body, state)
-    it = int(it)
-    dirs = direction_mod.trace_tuple(trace, it)
-    direction_mod.observe_trace(dirs, kernel="bfs")
-    return BFSResult(levels=levels, n_iterations=it, directions=dirs)
+        def body(state):
+            frontier, visited, levels, it, d, locked, trace = state
+            if cfg.mode == "auto":
+                # direction is loop-carried traced state — both branches
+                # are compiled once, the switch costs one predicate per
+                # iteration
+                nxt = jax.lax.cond(d == direction_mod.PULL, step_pull,
+                                   step_push, frontier, visited)
+            elif cfg.mode == "pull":
+                nxt = step_pull(frontier, visited)
+            else:
+                nxt = step_push(frontier, visited)
+            new_visited = visited | nxt
+            new_bits = nxt.unpack(jnp.int32)
+            levels_new = jnp.where((new_bits > 0) & (levels < 0), it + 1,
+                                   levels)
+            trace = direction_mod.record(trace, it, d)
+            d_next, locked = direction_mod.next_direction(
+                cfg, d, locked, direction_mod.nnz_words(nxt.words),
+                direction_mod.nnz_words(new_visited.words), n, avg_degree)
+            return (nxt, new_visited, levels_new, it + 1, d_next, locked,
+                    trace)
+
+        # the trace is sized by n (BFS runs at most n iterations), not by
+        # max_iters, so that max_iters stays a traced argument
+        state = (frontier, frontier, levels, jnp.int32(0),
+                 direction_mod.initial_direction(cfg), jnp.bool_(False),
+                 direction_mod.empty_trace(n))
+        _, _, levels, it, _, _, trace = jax.lax.while_loop(cond, body, state)
+        return levels, it, trace
+
+    return HoistedJit(loop)

@@ -42,12 +42,14 @@ so importing ``repro.core.graphblas`` does not pull in the Pallas stack.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 import sys
 import time
 import warnings
-from typing import Any, Callable, Dict, Iterable, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, Iterable, List, Optional, Tuple,
+                    Union)
 
 import jax.numpy as jnp
 
@@ -118,6 +120,47 @@ def set_resolve_hook(hook: Optional[Callable[[Key], None]]
     prev = _RESOLVE_HOOK
     _RESOLVE_HOOK = hook
     return prev
+
+
+def _run_resolve_hook(key: Key, t0: float) -> None:
+    if _RESOLVE_HOOK is None:
+        return
+    try:
+        _RESOLVE_HOOK(key)
+    except BaseException as e:
+        # the observe hook still sees the aborted resolution: injected
+        # faults must land in the telemetry exactly like real ones
+        _observe(key, t0, e)
+        raise
+
+
+#: key lists of the open :func:`recording` blocks
+_RECORDINGS: List[List[Key]] = []
+
+
+@contextlib.contextmanager
+def recording():
+    """Collect, in order, the key of every successful :func:`resolve`
+    made inside the block (a plan's trace: the kernels it bakes in)."""
+    keys: List[Key] = []
+    _RECORDINGS.append(keys)
+    try:
+        yield keys
+    finally:
+        _RECORDINGS.remove(keys)
+
+
+def replay(keys: Iterable[Key]) -> None:
+    """Put recorded keys through the resolve hook again.
+
+    A compiled plan resolves its kernels once, when it is traced; a warm
+    plan calls this per launch, so an installed hook (fault injection)
+    sees it as it would see a loop traced anew.
+    """
+    if _RESOLVE_HOOK is None:
+        return
+    for key in keys:
+        _run_resolve_hook(key, time.perf_counter())
 
 
 _OBSERVE_HOOK: Optional[Callable[[Key, float, Optional[BaseException]],
@@ -240,17 +283,12 @@ def resolve(op: str, rhs: str, out: str, backend: str, bucketed: bool,
             f"registered rows: {sorted(k for k in _REGISTRY if k[0] == op)}")
         _observe(key, t0, err)
         raise err
-    if _RESOLVE_HOOK is not None:
-        try:
-            _RESOLVE_HOOK(key)
-        except BaseException as e:
-            # the observe hook still sees the aborted resolution: injected
-            # faults must land in the telemetry exactly like real ones
-            _observe(key, t0, e)
-            raise
+    _run_resolve_hook(key, t0)
     _observe(key, t0, None)
     stats["resolves"] += 1
     last_key = key
+    for keys in _RECORDINGS:
+        keys.append(key)
     return fn
 
 

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +113,26 @@ class LowerTriangle:
         return self._parts[n_shards]
 
 
+class _LoopPlan:
+    """A compiled whole-graph loop and the dispatch keys its trace
+    resolved. Each later call replays those keys through the resolve hook
+    (``dispatch.replay``), so a fault injector still sees every kernel the
+    loop runs, once per call, as it did when each call traced the loop."""
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self.keys: Optional[Tuple] = None
+
+    def __call__(self, *args):
+        if self.keys is not None:
+            dispatch.replay(self.keys)
+            return self.fn(*args)
+        with dispatch.recording() as keys:
+            out = self.fn(*args)
+        self.keys = tuple(dict.fromkeys(keys))
+        return out
+
+
 @dataclasses.dataclass
 class GraphMatrix:
     """An immutable homogeneous-graph adjacency matrix, multi-format."""
@@ -161,6 +181,12 @@ class GraphMatrix:
     comm: str = "gather"
     xplan: Optional["partition_mod.ExchangePlan"] = None
     xplan_t: Optional["partition_mod.ExchangePlan"] = None
+    # compiled whole-graph loops (``loop_plan``). Not an __init__ field:
+    # every derived graph (``dataclasses.replace`` in with_backend,
+    # with_buckets, transposed, shard, unshard) starts with an empty memo,
+    # so a loop traced for one backend or layout never runs on another
+    loop_plans: dict = dataclasses.field(default_factory=dict, init=False,
+                                         repr=False, compare=False)
 
     # -- constructors -------------------------------------------------------
     @staticmethod
@@ -748,6 +774,32 @@ class GraphMatrix:
             ptr = self.csr.row_ptr
             self.degrees_cache = (ptr[1:] - ptr[:-1]).astype(jnp.float32)
         return self.degrees_cache
+
+    def loop_plan(self, kind: str, opts: Tuple,
+                  build: Callable[[], Callable]) -> Callable:
+        """This graph's compiled whole-graph loop ``kind`` (``"bfs"``,
+        ``"pagerank"``) for the static options ``opts``, made by ``build``
+        on the first call and reused by every later one.
+
+        The key also holds the layout the traced loop bakes in (backend,
+        bucketing, tile dim, mesh and comm). Lookups count as
+        ``plan_cache_{hits,misses}_total{kind,backend}``, the series the
+        engine's plan cache emits for its batched kinds.
+        """
+        mesh = (partition_mod.mesh_fingerprint(self.mesh, self.shard_axes)
+                if self.sharded else None)
+        key = (kind, opts, self.backend, self.use_buckets, self.tile_dim,
+               mesh, self.comm)
+        plan = self.loop_plans.get(key)
+        what = "misses" if plan is None else "hits"
+        if plan is None:
+            plan = self.loop_plans[key] = _LoopPlan(build())
+        from repro.obs import metrics as obs_metrics
+        if obs_metrics.enabled():
+            obs_metrics.get_registry().counter(
+                f"plan_cache_{what}_total", f"plan cache {what}",
+                ("kind", "backend")).inc(kind=kind, backend=self.backend)
+        return plan
 
     def fingerprint(self) -> str:
         """Content hash of the graph structure (the plan-cache key component).

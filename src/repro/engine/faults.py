@@ -17,7 +17,9 @@ transitions — is driven in tests and benchmarks by this injector instead:
     (:func:`repro.core.dispatch.set_resolve_hook`): every kernel
     resolution a plan trace performs can fault exactly where a broken
     kernel would. The server additionally calls :meth:`check` per group
-    launch, so warm plans (which never re-resolve) stay faultable too.
+    launch, and a graph's compiled whole-graph loop replays the keys its
+    trace resolved on every call (``dispatch.replay``), so warm plans
+    (which never re-resolve) stay faultable too.
 
 Faults surface as :class:`repro.core.dispatch.InjectedFault`, which the
 server treats like any other backend failure.
